@@ -11,6 +11,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 MODULES = [
     "table1_workload",      # Table 1
     "table2_platforms",     # Table 2
@@ -31,6 +33,7 @@ def main() -> None:
                     help="full-size sweeps (paper-scale)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     failures = 0
     for name in MODULES:

@@ -1,8 +1,4 @@
-"""Version-compat shims for the jax API surface this repo relies on.
-
-The repo targets the jax the container bakes in (0.4.x) while using the
-modern spellings where available, so the same source runs on both.
-"""
+"""The one ``shard_map`` spelling this repo uses."""
 from __future__ import annotations
 
 from typing import Any
@@ -13,41 +9,19 @@ __all__ = ["shard_map"]
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names: set[str] | None = None):
-    """``jax.shard_map`` with replication checking off, on any jax.
+    """``jax.shard_map`` with the replication (vma) check off.
 
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=...)``, where all
-    mesh axes are manual by default and a *subset* ``axis_names`` must be
-    spelled as the complementary ``auto`` axes. When the old API predates
-    the ``auto`` parameter the request cannot be honoured — that raises
-    instead of silently treating every axis as manual (which would change
-    collective semantics between jax versions). The replication check is
-    disabled in both spellings for the same reason: our workers derive
-    varying values from ``axis_index``, which the static analysis cannot
-    see through.
+    ``axis_names`` names the manual mesh axes (all of them when None) and
+    must be a subset of the mesh's. The check is off because our workers
+    derive varying values from ``axis_index``, which the static analysis
+    cannot see through.
     """
     if axis_names is not None and not set(axis_names) <= set(mesh.axis_names):
         raise ValueError(
             f"axis_names {sorted(axis_names)} not a subset of mesh axes "
             f"{mesh.axis_names}")
-    if hasattr(jax, "shard_map"):
-        kwargs: dict[str, Any] = {"check_vma": False}
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    import inspect
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {"check_rep": False}
-    if axis_names is not None and set(axis_names) != set(mesh.axis_names):
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if "auto" not in inspect.signature(_shard_map).parameters:
-            raise NotImplementedError(
-                f"this jax's shard_map cannot leave axes {sorted(auto)} "
-                f"automatic (no `auto` parameter); pass axis_names covering "
-                f"every mesh axis or upgrade jax")
-        kwargs["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    kwargs: dict[str, Any] = {"check_vma": False}
+    if axis_names is not None:
+        kwargs["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

@@ -52,7 +52,8 @@ import numpy as np
 
 from repro.core.allocation import CapacityError, linear_work_reduction
 from repro.core.metrics import CombinedModel, LatencyModel, fit_latency_model
-from repro.runtime.domain import Domain, MeshPlatformSpec, PlatformSpec, seed_for
+from repro.runtime.domain import (Domain, MeshPlatformSpec, PlatformSpec,
+                                  local_device_spec, seed_for)
 from repro.runtime.scenario import Scenario, apply_scenario, salvage_runs
 
 __all__ = [
@@ -265,13 +266,7 @@ class LocalLMPlatform(_LMPlatformBase):
 
     def __init__(self, name: str = "Local JAX LM", rtt_ms: float = 0.05,
                  tp: int = 1):
-        if tp > 1:
-            self.spec: PlatformSpec = MeshPlatformSpec(
-                name, "CPU", "jax-cpu", "localhost",
-                gflops=float("nan"), rtt_ms=rtt_ms, mesh_shape=(1, tp))
-        else:
-            self.spec = PlatformSpec(name, "CPU", "jax-cpu", "localhost",
-                                     gflops=float("nan"), rtt_ms=rtt_ms)
+        self.spec = local_device_spec(name, rtt_ms, tp)
         self.tp = int(tp)
         self._mesh = None
         self._engines: dict[tuple, object] = {}
@@ -286,7 +281,8 @@ class LocalLMPlatform(_LMPlatformBase):
             self._mesh = make_host_mesh(data=1, model=self.tp)
         return self._mesh
 
-    def _engine(self, req: LMRequest):
+    def engine(self, req: LMRequest):
+        """The (built, warmed) engine serving ``req``'s family."""
         key = (req.arch, req.smoke, req.batch, req.prompt_len, req.max_seq)
         eng = self._engines.get(key)
         if eng is None:
@@ -305,7 +301,7 @@ class LocalLMPlatform(_LMPlatformBase):
 
     def run(self, req: LMRequest, n_tokens: int, seed: int = 0) -> ServeRecord:
         n = self._clamp(req, n_tokens)
-        result = self._engine(req).generate(n, seed=seed)
+        result = self.engine(req).generate(n, seed=seed)
         return ServeRecord(self.spec.name, req.task_id, n,
                            result.total_latency, result.prefill_latency)
 
@@ -325,7 +321,7 @@ class LocalLMPlatform(_LMPlatformBase):
                 for r in reqs}) > 1:
             return super().run_batch(reqs, tokens, seed=seed)
         self._admission_guard(reqs, tokens)
-        engine = self._engine(reqs[0])
+        engine = self.engine(reqs[0])
         out: list[ServeRecord] = []
         wave: list[int] = []
         held = 0.0

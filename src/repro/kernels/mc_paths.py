@@ -10,9 +10,9 @@ back-ends) re-thought for the TPU memory hierarchy:
     in-register on the VPU: no RNG state to load/store, and the stream for
     (path, step) is identical no matter how paths are tiled across blocks
     or devices.
-  * The only HBM traffic is the per-block output: (sum payoff, sum
-    payoff^2) — 8 bytes out per ~10^5-10^6 FLOPs of path work, i.e. the
-    kernel is pure-compute by construction (arithmetic intensity ~1e5).
+  * The only HBM traffic is the output: per task one lane-dense (8, 128)
+    tile of per-lane (sum payoff, sum payoff^2), resident in VMEM across
+    that task's path blocks — the kernel is pure-compute by construction.
   * Payoffs need only 4 path statistics (terminal, mean, min, max), all
     accumulated in registers, so one kernel serves every Table 1 contract.
 
@@ -174,12 +174,15 @@ def _mc_batch_kernel(params_ref, tid_ref, kind_ref, nact_ref, seed_ref, o_ref,
     """One (task, path-block) grid step of the family-batched kernel.
 
     Per-task scalars (spot, rate, dt, vol/Heston params, strike, barriers,
-    payout, call sign) arrive through an SMEM params ref whose BlockSpec is
-    indexed by ``pl.program_id(0)`` — they are *runtime operands*, so the
-    compiled kernel is shared by every task of the family.  The path tile
-    design is unchanged from the single-task kernel: a
-    (block_paths // LANES, LANES) stack of VREG rows resident for the whole
-    time loop, with only (sum, sumsq) leaving for HBM.
+    payout, call sign) sit in SMEM as whole arrays indexed by
+    ``pl.program_id(0)`` — they are *runtime operands*, so the compiled
+    kernel is shared by every task of the family.  The path tile design is
+    unchanged from the single-task kernel: a (block_paths // LANES, LANES)
+    stack of VREG rows resident for the whole time loop.
+
+    Each task owns one lane-dense (SUBLANES, LANES) output tile that stays
+    resident across its path blocks (the ``"arbitrary"`` grid axis): row 0
+    accumulates per-lane sums of the payoff, row 1 of its square.
 
     Paths with global id >= n_active (batch padding for ragged per-task
     path counts) are simulated but masked out of the payoff sums, so each
@@ -187,21 +190,25 @@ def _mc_batch_kernel(params_ref, tid_ref, kind_ref, nact_ref, seed_ref, o_ref,
     draws — bit-identical in distribution to the per-task run.
     """
     rows = block_paths // LANES
+    task = pl.program_id(0)
     block = pl.program_id(1)
 
-    base = block * block_paths
+    base = (block * block_paths).astype(jnp.uint32)
     pid = (base
            + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANES), 0) * LANES
            + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANES), 1))
     k0 = seed_ref[0]
-    k1 = tid_ref[0]
+    k1 = tid_ref[task]
 
-    spot = jnp.full((rows, LANES), params_ref[0, COL["spot"]])
-    rate = params_ref[0, COL["rate"]]
-    dt = params_ref[0, COL["dt"]]
+    def param(name):
+        return params_ref[task, COL[name]]
+
+    spot = jnp.full((rows, LANES), param("spot"))
+    rate = param("rate")
+    dt = param("dt")
 
     if model_kind == "black-scholes":
-        f = bs_step_fn(rate, params_ref[0, COL["vol"]], dt)
+        f = bs_step_fn(rate, param("vol"), dt)
 
         def step(s_idx, state):
             s, acc, mn, mx = state
@@ -212,10 +219,8 @@ def _mc_batch_kernel(params_ref, tid_ref, kind_ref, nact_ref, seed_ref, o_ref,
         init: Any = (spot, jnp.zeros_like(spot), spot, spot)
         s_t, acc, mn, mx = jax.lax.fori_loop(0, n_steps, step, init)
     else:
-        f = heston_step_fn(rate, params_ref[0, COL["kappa"]],
-                           params_ref[0, COL["theta"]],
-                           params_ref[0, COL["xi"]],
-                           params_ref[0, COL["rho"]], dt)
+        f = heston_step_fn(rate, param("kappa"), param("theta"), param("xi"),
+                           param("rho"), dt)
 
         def step(s_idx, state):
             s, v, acc, mn, mx = state
@@ -223,19 +228,27 @@ def _mc_batch_kernel(params_ref, tid_ref, kind_ref, nact_ref, seed_ref, o_ref,
             s, v = f((s, v), z)
             return s, v, acc + s, jnp.minimum(mn, s), jnp.maximum(mx, s)
 
-        init = (spot, jnp.full((rows, LANES), params_ref[0, COL["v0"]]),
+        init = (spot, jnp.full((rows, LANES), param("v0")),
                 jnp.zeros_like(spot), spot, spot)
         s_t, _, acc, mn, mx = jax.lax.fori_loop(0, n_steps, step, init)
 
     avg = acc / jnp.float32(n_steps)
     pay = payoff_from_stats_coded(
         s_t, avg, mn, mx,
-        strike=params_ref[0, COL["strike"]], lower=params_ref[0, COL["lower"]],
-        upper=params_ref[0, COL["upper"]], payout=params_ref[0, COL["payout"]],
-        call_sign=params_ref[0, COL["call_sign"]], kind=kind_ref[0])
-    pay = jnp.where(pid < nact_ref[0], pay, jnp.float32(0.0))
-    o_ref[0, 0, 0] = jnp.sum(pay)
-    o_ref[0, 0, 1] = jnp.sum(pay * pay)
+        strike=param("strike"), lower=param("lower"), upper=param("upper"),
+        payout=param("payout"), call_sign=param("call_sign"),
+        kind=kind_ref[task])
+    pay = jnp.where(pid < nact_ref[task], pay, jnp.float32(0.0))
+
+    @pl.when(block == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+    lane_sum = jnp.sum(pay, axis=0, keepdims=True)
+    lane_sq = jnp.sum(pay * pay, axis=0, keepdims=True)
+    o_ref[...] += jnp.where(row == 0, lane_sum,
+                            jnp.where(row == 1, lane_sq, jnp.float32(0.0)))
 
 
 def mc_moments_batch_kernel_call(batch: TaskBatch, n_active, seed,
@@ -247,8 +260,9 @@ def mc_moments_batch_kernel_call(batch: TaskBatch, n_active, seed,
     ``n_active`` is a (T,) uint32 array of per-task path counts;
     ``n_paths_max`` (a multiple of ``block_paths``) sets the padded grid.
     ``seed`` is a (1,) uint32 array — a runtime operand, so re-seeding the
-    benchmark ladder never retraces.  Returns (T, blocks, 2) partial
-    (sum, sumsq) per (task, block).
+    benchmark ladder never retraces.  Returns (T, SUBLANES, LANES) tiles
+    whose row 0 holds per-lane payoff sums and row 1 per-lane sums of
+    squares; every other row is zero.
     """
     blocks = validate_blocking(n_paths_max, block_paths)
     T = batch.n_tasks
@@ -257,19 +271,15 @@ def mc_moments_batch_kernel_call(batch: TaskBatch, n_active, seed,
         _mc_batch_kernel, model_kind=batch.model_kind,
         block_paths=block_paths, n_steps=batch.n_steps,
     )
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole array, scalar reads
     return pl.pallas_call(
         kernel,
         grid=(T, blocks),
-        in_specs=[
-            smem((1, len(COL)), lambda t, b: (t, 0)),  # params row
-            smem((1,), lambda t, b: (t,)),             # task_id
-            smem((1,), lambda t, b: (t,)),             # payoff kind
-            smem((1,), lambda t, b: (t,)),             # n_active
-            smem((1,), lambda t, b: (0,)),             # seed
-        ],
-        out_specs=pl.BlockSpec((1, 1, 2), lambda t, b: (t, b, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, blocks, 2), jnp.float32),
+        in_specs=[smem] * 5,  # params, task_id, payoff kind, n_active, seed
+        out_specs=pl.BlockSpec((None, SUBLANES, LANES), lambda t, b: (t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, SUBLANES, LANES), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(batch.params, batch.task_ids, batch.payoff_kinds,
       jnp.asarray(n_active, jnp.uint32), jnp.asarray(seed, jnp.uint32))

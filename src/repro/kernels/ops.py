@@ -2,7 +2,6 @@
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,26 +17,13 @@ from .mc_paths import (
 __all__ = ["mc_moments", "mc_moments_batch", "default_interpret"]
 
 
-@functools.cache
-def _no_tpu_present() -> bool:
-    try:
-        return not any(d.platform == "tpu" for d in jax.devices())
-    except RuntimeError:  # no backends initialised at all
-        return True
-
-
 def default_interpret() -> bool:
-    """Interpret the Pallas kernels only when no TPU is present.
+    """Interpret the Pallas kernels only on JAX's CPU backend.
 
-    Override with ``REPRO_PALLAS_INTERPRET=1`` (force the interpreter, e.g.
-    for debugging on TPU hosts) or ``=0`` (force compiled mode).  The env
-    var is re-read on every call so it can be toggled at runtime; only the
-    device probe is cached.
+    Everywhere else the kernel is compiled: a device that cannot compile it
+    fails loudly instead of falling back to the interpreter.
     """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.lower() not in ("0", "false", "no")
-    return _no_tpu_present()
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit,
@@ -45,10 +31,10 @@ def default_interpret() -> bool:
 def _mc_moments_batch_jit(batch: TaskBatch, n_active, seed, *,
                           n_paths_max: int, block_paths: int, interpret: bool):
     record_trace("pallas_batch")
-    partial = mc_moments_batch_kernel_call(
+    tiles = mc_moments_batch_kernel_call(
         batch, n_active, seed, n_paths_max=n_paths_max,
         block_paths=block_paths, interpret=interpret)
-    return partial[:, :, 0].sum(axis=1), partial[:, :, 1].sum(axis=1)
+    return tiles[:, 0].sum(axis=1), tiles[:, 1].sum(axis=1)
 
 
 def mc_moments_batch(batch: TaskBatch, n_active, seed: int = 0,

@@ -58,13 +58,18 @@ def uniforms(k0, k1, x0, x1):
 
     The top 24 bits are used so the uint->float conversion is exact in
     float32 (values >= 2**24 would round and could push u to exactly 1.0,
-    which poisons log(u) in Box-Muller).
+    which poisons log(u) in Box-Muller).  The conversion goes through
+    int32, which holds those 24 bits exactly: the TPU kernel compiler has
+    no uint32 -> float32 cast.
     """
     a, b = threefry2x32(k0, k1, x0, x1)
     scale = jnp.float32(2.0**-24)
-    u0 = ((a >> jnp.uint32(8)).astype(jnp.float32) + jnp.float32(0.5)) * scale
-    u1 = ((b >> jnp.uint32(8)).astype(jnp.float32) + jnp.float32(0.5)) * scale
-    return u0, u1
+
+    def to_unit(bits):
+        top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+        return (top.astype(jnp.float32) + jnp.float32(0.5)) * scale
+
+    return to_unit(a), to_unit(b)
 
 
 def normal_pair(k0, k1, x0, x1):

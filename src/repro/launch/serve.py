@@ -59,7 +59,6 @@ class ServeEngine:
         self.prompt_len = prompt_len
         self.max_seq = max_seq or (prompt_len + 64)
         self.model = build_model(cfg)
-        self.params = self.model.init(jax.random.PRNGKey(seed))
         self.mesh = mesh
         tp = mesh.shape.get("model", 1) if mesh is not None else 1
         if mesh is not None and mesh.shape.get("data", 1) > 1:
@@ -71,33 +70,76 @@ class ServeEngine:
                 f"data axis {mesh.shape['data']} — batch-parallel serving "
                 f"is not supported yet, pass make_host_mesh(data=1, "
                 f"model={tp})")
+        key = jax.random.PRNGKey(seed)
         if tp > 1:
-            # tensor-parallel step functions; logits stay bitwise-equal
-            # to the single-device path (see repro.launch.tp)
-            from repro.launch.tp import build_tp_step_fns
+            from jax.sharding import NamedSharding
 
-            prefill, decode = build_tp_step_fns(self.model, self.params,
-                                                mesh, self.max_seq)
+            from repro.launch.tp import build_tp_step_fns, tp_param_specs, validate_tp
+
+            validate_tp(cfg, tp)
+            specs = tp_param_specs(jax.eval_shape(self.model.init, key),
+                                   self.model.block_key)
+            # placed sharded once, by the init itself: no parameter is ever
+            # whole on one device, and the step functions' in_specs match
+            # these shardings, so no step reshards them
+            self.params = jax.jit(self.model.init, out_shardings={
+                k: NamedSharding(mesh, spec) for k, spec in specs.items()})(key)
+            prefill, decode = build_tp_step_fns(self.model, specs, mesh,
+                                                self.max_seq)
             self._prefill = jax.jit(prefill)
             self._decode = jax.jit(decode)
         else:
+            self.params = jax.jit(self.model.init)(key)
             self._prefill = jax.jit(
                 lambda p, b: self.model.prefill(p, b, self.max_seq))
             self._decode = jax.jit(self.model.decode_step)
         self._warm = False
 
-    def probe_logits(self, seed: int = 0):
-        """(prefill logits, one greedy decode step's logits) as numpy —
-        the parity probe used to assert the sharded path is bitwise."""
+    def probe_logits(self, seed: int = 0, steps: int = 1, tokens=None):
+        """(prefill logits [B, 1, V], logits of ``steps`` decode steps
+        [B, steps, V]) as numpy — the probe that compares engines serving
+        one configuration on different meshes. Decode reads the greedy
+        token of the logits before it, or ``tokens[:, j]`` at step ``j``
+        when ``tokens`` [B, steps] is given (teacher forcing, so that two
+        engines stay on one token sequence)."""
         import jax.numpy as jnp
         import numpy as np
 
         self.warm(seed)
+        cache, logits = self._prefill(self.params, self._batch_inputs(seed))
+        first = np.asarray(logits)
+        decoded = []
+        for j in range(steps):
+            toks = (jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+                    if tokens is None else
+                    jnp.asarray(tokens[:, j:j + 1], jnp.int32))
+            cache, logits = self._decode(self.params, cache, toks)
+            decoded.append(np.asarray(logits))
+        return first, np.concatenate(decoded, axis=1)
+
+    def decode_consistency(self, steps: int, seed: int = 0):
+        """(logits of ``steps`` greedy decode steps through the KV cache,
+        logits at the same positions of one prefill over the prompt
+        extended by the tokens those steps read), both [B, steps, V] numpy.
+        The two compute the same function; they differ only by the compute
+        dtype's rounding. Dense decoder families only (the prefill returns
+        the logits of its last ``steps`` positions)."""
+        import jax
+        import numpy as np
+
+        if not 1 <= steps <= self.max_seq - self.prompt_len:
+            raise ValueError(
+                f"steps={steps} must be in [1, max_seq - prompt_len = "
+                f"{self.max_seq - self.prompt_len}]")
         batch = self._batch_inputs(seed)
-        cache, logits = self._prefill(self.params, batch)
-        toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        _, dlogits = self._decode(self.params, cache, toks)
-        return np.asarray(logits), np.asarray(dlogits)
+        first, decoded = self.probe_logits(seed, steps)
+        # decode step j read the greedy token of the logits before it
+        read = np.concatenate([first, decoded[:, :-1]], axis=1).argmax(-1)
+        tokens = np.concatenate([batch["tokens"], read.astype(np.int32)], axis=1)
+        _, ref = jax.jit(lambda p, b: self.model.prefill(
+            p, b, self.max_seq, n_logits=steps))(
+                self.params, {**batch, "tokens": tokens})
+        return decoded, np.asarray(ref)
 
     def _batch_inputs(self, seed: int):
         from repro.data.pipeline import batch_for
@@ -219,8 +261,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import numpy as np
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.core.metrics import fit_latency_model
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -1,24 +1,28 @@
-"""Tensor-parallel serving step functions (gather-based, bitwise-exact).
+"""Tensor-parallel serving step functions (gather-based).
 
-The sharded path must produce *bitwise* the logits of the single-device
-engine — it is the same platform quoted at a different mesh shape, and
-the allocator's accountability story dies the moment "same work, wider
-mesh" changes the answer. psum-based (Megatron-style row-parallel)
-output projections reassociate the contraction across devices and are
-NOT bitwise; this module therefore shards only *column-parallel* weights
-(q/k/v heads, MLP hidden, unembed vocab) and **all-gathers activations**
-back to full width before every contraction-sharded matmul, which then
-runs replicated. ``all_gather(tiled=True)`` concatenates shards in axis
-order, so gathered tensors are elementwise identical to their dense
-layout and every remaining op is the exact computation the dense path
-runs.
+The sharded path must compute the single-device engine's logits — it is
+the same platform quoted at a different mesh shape, and the allocator's
+accountability story dies the moment "same work, wider mesh" changes the
+answer. psum-based (Megatron-style row-parallel) output projections split
+each contraction across devices and add the partial sums; this module
+instead shards only *column-parallel* weights (q/k/v heads, MLP hidden,
+unembed vocab) and **all-gathers activations** back to full width before
+every contraction-sharded matmul, which then runs replicated.
+``all_gather(tiled=True)`` concatenates shards in axis order, so gathered
+tensors are elementwise identical to their dense layout and every op
+computes the same per-element arithmetic as the dense path.
+
+That is not a bitwise guarantee: the compiler may pick other dot and
+fusion strategies for the partitioned program's smaller shapes, which
+reorders float sums (XLA:CPU in JAX 0.9 does so for the per-device
+attention with one kv head: a few float32 ulps). Tests therefore hold the
+sharded logits to a float tolerance and identical greedy tokens.
 
 The KV cache shards on the kv-head axis — the genuine pooled-KV win —
 which requires ``n_kv_heads % tp == 0``; GQA head groups then stay
 contiguous per device (device ``p`` holds q heads ``[p*h/tp, ...)`` and
 exactly their kv heads). Other widths raise :class:`TPShardingError`
-(kv-head *replication* for tp > n_kv_heads drifts by ~1 ulp in decode
-and is deliberately not offered as an "exact" path).
+(kv-head *replication* for tp > n_kv_heads is not offered).
 """
 from __future__ import annotations
 
@@ -53,13 +57,13 @@ def validate_tp(cfg, tp: int) -> None:
     if bad:
         raise TPShardingError(
             f"{cfg.name}: tp={tp} must divide every sharded axis; "
-            f"indivisible: {bad} (kv-head replication is not offered — "
-            f"it is not bitwise-exact)")
+            f"indivisible: {bad} (kv-head replication is not offered)")
 
 
 def tp_param_specs(params: dict, block_key: str = "blocks") -> dict:
-    """PartitionSpec per param: column-parallel shards on the model axis,
-    everything contraction-sharded in Megatron stays replicated here."""
+    """PartitionSpec per param (arrays or shapes): column-parallel shards
+    on the model axis, everything contraction-sharded in Megatron stays
+    replicated here."""
     specs = {}
     for k, v in params.items():
         stacked = k.startswith(block_key + "/")
@@ -142,16 +146,15 @@ def _tp_forward(cfg, block_key: str):
     return fwd
 
 
-def build_tp_step_fns(model, params: dict, mesh, max_seq: int):
+def build_tp_step_fns(model, pspecs: dict, mesh, max_seq: int):
     """(prefill, decode) callables matching ``DenseModel.prefill`` /
     ``decode_step`` signatures, tensor-parallel over ``mesh``'s model
-    axis. Raises :class:`TPShardingError` for unshardable shapes."""
+    axis, for parameters laid out as ``pspecs`` (:func:`tp_param_specs`).
+    Raises :class:`TPShardingError` for unshardable shapes."""
     cfg = model.cfg
     tp = mesh.shape[MODEL]
     validate_tp(cfg, tp)
-    block_key = model.block_key
-    fwd = _tp_forward(cfg, block_key)
-    pspecs = tp_param_specs(params, block_key)
+    fwd = _tp_forward(cfg, model.block_key)
     cache_spec = tp_cache_specs()
     out_specs = (cache_spec, P(None, None, None))
     kvh_local = cfg.n_kv_heads // tp

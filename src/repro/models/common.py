@@ -127,18 +127,22 @@ def subtree(params: Params, prefix: str) -> Params:
 
 def stack_init(builder_fn: Callable[[jax.Array], tuple[Params, dict]],
                key: jax.Array, n: int) -> tuple[Params, dict]:
-    """Initialise ``n`` copies of a layer and stack them on a leading dim,
-    prepending None to each spec (the layer-stack dim is never sharded)."""
+    """Initialise ``n`` copies of a layer stacked on a leading dim,
+    prepending None to each spec (the layer-stack dim is never sharded).
+
+    The copies come from one ``lax.map`` over per-layer keys: the layer is
+    traced and compiled once, not ``n`` times, and the stacked arrays are
+    written in place (no second copy while stacking)."""
     keys = jax.random.split(key, n)
-    stacked: dict[str, list] = {}
     specs: dict[str, P] = {}
-    for i in range(n):
-        p, s = builder_fn(keys[i])
-        for k, v in p.items():
-            stacked.setdefault(k, []).append(v)
-        if i == 0:
-            specs = {k: P(None, *tuple(sp)) for k, sp in s.items()}
-    return {k: jnp.stack(v) for k, v in stacked.items()}, specs
+
+    def one(k):
+        p, s = builder_fn(k)
+        specs.update(s)
+        return p
+
+    stacked = jax.lax.map(one, keys)
+    return stacked, {k: P(None, *tuple(sp)) for k, sp in specs.items()}
 
 
 def shard_act(x: jnp.ndarray, spec: P | None, rules: "Rules | None" = None):

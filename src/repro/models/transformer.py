@@ -168,12 +168,14 @@ class DenseModel:
         new_cache = {"k": ks, "v": vs, "pos": cache["pos"] + x.shape[1]}
         return x, new_cache
 
-    def prefill(self, params, batch, max_seq: int, q_chunk=None):
+    def prefill(self, params, batch, max_seq: int, q_chunk=None,
+                n_logits: int = 1):
+        """-> (cache, logits of the last ``n_logits`` positions [B, n, V])."""
         cfg = self.cfg
         x = self.embed_inputs(params, batch)
         cache = self.init_cache(x.shape[0], max_seq)
         x, cache = self._blocks_with_cache(params, x, cache, q_chunk=q_chunk)
-        x = rmsnorm(x[:, -1:], params["ln_f"], cfg.eps)
+        x = rmsnorm(x[:, -n_logits:], params["ln_f"], cfg.eps)
         return cache, (x @ params["unembed"]).astype(jnp.float32)
 
     def decode_step(self, params, cache, tokens):

@@ -44,7 +44,7 @@ from repro.core.metrics import (
     fit_accuracy_model,
     fit_latency_model,
 )
-from repro.runtime.domain import PlatformSpec
+from repro.runtime.domain import PlatformSpec, local_device_spec
 from repro.runtime.scenario import Scenario, apply_scenario, salvage_runs
 from .contracts import Heston, PricingTask, group_by_launch
 from . import mc
@@ -140,8 +140,7 @@ class LocalJaxPlatform:
     def __init__(self, name: str = "Local JAX", backend: str = "jnp",
                  rtt_ms: float = 0.05):
         self.backend = backend
-        self.spec = PlatformSpec(name, "CPU", "jax-cpu", "localhost",
-                                 gflops=float("nan"), rtt_ms=rtt_ms)
+        self.spec = local_device_spec(name, rtt_ms)
 
     def run_batch(self, tasks: Sequence[PricingTask], n_paths,
                   seed: int = 0) -> list[RunRecord]:

