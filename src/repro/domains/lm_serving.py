@@ -52,6 +52,7 @@ import numpy as np
 
 from repro.core.allocation import CapacityError, linear_work_reduction
 from repro.core.metrics import CombinedModel, LatencyModel, fit_latency_model
+from repro.obs.trace import default_tracer
 from repro.runtime.domain import (Domain, MeshPlatformSpec, PlatformSpec,
                                   local_device_spec, seed_for)
 from repro.runtime.scenario import Scenario, apply_scenario, salvage_runs
@@ -314,37 +315,41 @@ class LocalLMPlatform(_LMPlatformBase):
         (:meth:`repro.launch.serve.ServeEngine.generate_many`) in KV-gated
         admission waves: a wave joins when its pages fit ``mem_bytes``,
         each request leaves the step its target is reached. Mixed-family
-        calls fall back to solo serves."""
-        tokens = [self._clamp(r, n) for r, n in
-                  zip(reqs, _as_token_list(reqs, n_tokens))]
-        if len({(r.arch, r.smoke, r.batch, r.prompt_len, r.max_seq)
-                for r in reqs}) > 1:
-            return super().run_batch(reqs, tokens, seed=seed)
-        self._admission_guard(reqs, tokens)
-        engine = self.engine(reqs[0])
-        out: list[ServeRecord] = []
-        wave: list[int] = []
-        held = 0.0
-        cap = self.spec.total_mem_bytes
+        calls fall back to solo serves. The call is one ``lm.run_batch``
+        span on the process tracer."""
+        with default_tracer().span("lm.run_batch", track="serve",
+                                   requests=len(reqs)):
+            tokens = [self._clamp(r, n) for r, n in
+                      zip(reqs, _as_token_list(reqs, n_tokens))]
+            if len({(r.arch, r.smoke, r.batch, r.prompt_len, r.max_seq)
+                    for r in reqs}) > 1:
+                return super().run_batch(reqs, tokens, seed=seed)
+            self._admission_guard(reqs, tokens)
+            engine = self.engine(reqs[0])
+            out: list[ServeRecord] = []
+            wave: list[int] = []
+            held = 0.0
+            cap = self.spec.total_mem_bytes
 
-        def flush():
-            if not wave:
-                return
-            results = engine.generate_many([tokens[i] for i in wave], seed=seed)
-            for i, res in zip(wave, results):
-                out.append(ServeRecord(self.spec.name, reqs[i].task_id,
-                                       tokens[i], res.total_latency,
-                                       res.prefill_latency))
+            def flush():
+                if not wave:
+                    return
+                results = engine.generate_many([tokens[i] for i in wave],
+                                               seed=seed)
+                for i, res in zip(wave, results):
+                    out.append(ServeRecord(self.spec.name, reqs[i].task_id,
+                                           tokens[i], res.total_latency,
+                                           res.prefill_latency))
 
-        for i, (req, n) in enumerate(zip(reqs, tokens)):
-            need = request_kv_bytes(req, n)
-            if wave and held + need > cap:
-                flush()
-                wave, held = [], 0.0
-            wave.append(i)
-            held += need
-        flush()
-        return out
+            for i, (req, n) in enumerate(zip(reqs, tokens)):
+                need = request_kv_bytes(req, n)
+                if wave and held + need > cap:
+                    flush()
+                    wave, held = [], 0.0
+                wave.append(i)
+                held += need
+            flush()
+            return out
 
 
 class SimulatedLMPlatform(_LMPlatformBase):

@@ -18,6 +18,7 @@ import time
 from typing import Any
 
 from repro.obs.log import get_logger
+from repro.obs.trace import default_tracer
 
 log = get_logger("launch.serve")
 
@@ -90,8 +91,12 @@ class ServeEngine:
             self._decode = jax.jit(decode)
         else:
             self.params = jax.jit(self.model.init)(key)
-            self._prefill = jax.jit(
-                lambda p, b: self.model.prefill(p, b, self.max_seq))
+
+            def prefill(params, batch):
+                return self.model.prefill(params, batch, self.max_seq)
+
+            # a named function, so the profile shows ``jit_prefill``
+            self._prefill = jax.jit(prefill)
             self._decode = jax.jit(self.model.decode_step)
         self._warm = False
 
@@ -204,6 +209,11 @@ class ServeEngine:
         exactly as on a continuous-batching server. Per-stream sums
         therefore add up to the engine's true busy time, which is what the
         allocator's records must reflect.
+
+        Spans (on the process tracer): ``serve.generate`` around the call,
+        ``serve.prefill``, one ``serve.decode`` a step, and inside them
+        ``serve.wait`` (the device sync) and ``serve.fetch`` (the token's
+        copy to the host).
         """
         import jax.numpy as jnp
         import numpy as np
@@ -219,25 +229,34 @@ class ServeEngine:
                 f"max_seq {self.max_seq}")
         self.warm(seed)
         batch = self._batch_inputs(seed)
+        span = default_tracer().span
 
-        t0 = time.perf_counter()
-        cache, logits = self._prefill(self.params, batch)
-        logits.block_until_ready()
-        t_prefill = (time.perf_counter() - t0) / len(gens)
+        with span("serve.generate", track="serve", streams=len(gens),
+                  steps=max(gens)):
+            with span("serve.prefill", track="serve", rows=self.batch):
+                t0 = time.perf_counter()
+                cache, logits = self._prefill(self.params, batch)
+                with span("serve.wait", track="serve"):
+                    logits.block_until_ready()
+                t_prefill = (time.perf_counter() - t0) / len(gens)
 
-        toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        generated = [np.asarray(toks)]
-        per_stream: list[list[float]] = [[] for _ in gens]
-        for step in range(max(gens)):
-            t0 = time.perf_counter()
-            cache, logits = self._decode(self.params, cache, toks)
             toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-            toks.block_until_ready()
-            step_lat = time.perf_counter() - t0
-            generated.append(np.asarray(toks))
-            active = [i for i, g in enumerate(gens) if g > step]
-            for i in active:
-                per_stream[i].append(step_lat / len(active))
+            generated = [np.asarray(toks)]
+            per_stream: list[list[float]] = [[] for _ in gens]
+            for step in range(max(gens)):
+                with span("serve.decode", track="serve"):
+                    t0 = time.perf_counter()
+                    cache, logits = self._decode(self.params, cache, toks)
+                    toks = jnp.argmax(logits[:, -1:],
+                                      axis=-1).astype(jnp.int32)
+                    with span("serve.wait", track="serve"):
+                        toks.block_until_ready()
+                    step_lat = time.perf_counter() - t0
+                    with span("serve.fetch", track="serve"):
+                        generated.append(np.asarray(toks))
+                active = [i for i, g in enumerate(gens) if g > step]
+                for i in active:
+                    per_stream[i].append(step_lat / len(active))
         tokens = np.concatenate(generated, axis=1)
         return [GenerationResult(prefill_latency=t_prefill,
                                  decode_latencies=per_stream[i],

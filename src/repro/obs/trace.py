@@ -21,19 +21,38 @@ trace-event JSON array format (``B``/``E`` duration events plus ``i``
 instants and ``M`` thread-name metadata, one ``tid`` per track), which
 loads directly in Perfetto / ``chrome://tracing``.
 
-Everything is off by default and zero-dependency: a disabled tracer's
+Everything is off by default and zero-dependency: when nobody traces,
 :meth:`Tracer.span` returns a shared no-op context manager (no allocation,
-no lock), so instrumented code paths cost nothing measurable when tracing
-is off. ``REPRO_TRACE=1`` enables the process-default tracer and registers
-an atexit hook that writes ``REPRO_TRACE_PATH`` (default
-``repro_trace.json``); ``Scheduler(trace=...)`` scopes a tracer to one
-scheduler instead.
+no lock), so instrumented code paths cost one flag check. ``REPRO_TRACE=1``
+enables the process-default tracer and registers an atexit hook that
+writes ``REPRO_TRACE_PATH`` (default ``repro_trace.json``);
+``Scheduler(trace=...)`` scopes a tracer to one scheduler instead.
+
+A JAX profiler session (``jax.profiler.trace`` / ``start_trace``) is the
+second switch: while one runs, every tracer's :meth:`Tracer.span` records,
+and each recorded span also opens a ``jax.profiler.TraceAnnotation`` of
+its name, so the spans appear on the profile's host plane beside the
+device's lines, on the profile's clock. JAX is looked up only once it has
+been imported. Instants, retroactive spans and the bookkeeping built on
+them (the ledger, solver-phase lifting) stay on ``enabled`` alone.
+:meth:`Tracer.spans_in` reads finished spans back in
+``time.perf_counter`` seconds, which one offset maps onto the profile. A
+disabled tracer keeps only the newest ``Tracer.SESSION_SPANS`` of the
+spans a session made it record, for ``spans_in``, and never exports them.
+
+The program's own spans below the scheduler (the serving engine's
+``serve.*``, the pricing platform's and engine's ``pricing.*``, the LM
+platform's ``lm.run_batch``) open on the process tracer,
+:func:`default_tracer`; a scheduler's scoped tracer does not receive them.
 """
 from __future__ import annotations
 
 import atexit
+import collections
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from math import inf, isfinite
@@ -54,6 +73,21 @@ def env_enabled() -> bool:
         "", "0", "false", "off", "no")
 
 
+#: ``jax.profiler.TraceAnnotation`` once JAX has been imported, else None.
+_ANNOTATION = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` if JAX has been imported (never
+    imports it: a process without JAX runs no profiler session)."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
 class Span:
     """One interval on a track; also its own context manager.
 
@@ -64,10 +98,10 @@ class Span:
     """
 
     __slots__ = ("name", "track", "cat", "t0", "t1", "args",
-                 "_tracer", "_seq0", "_seq1")
+                 "_tracer", "_seq0", "_seq1", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: str, cat: str,
-                 args: dict):
+                 args: dict, ann=None):
         self._tracer = tracer
         self.name = name
         self.track = track
@@ -75,6 +109,8 @@ class Span:
         self.args = args
         self.t0 = self.t1 = 0.0
         self._seq0 = self._seq1 = 0
+        #: the profiler annotation opened with the span, under a session
+        self._ann = ann
 
     def set_virtual(self, v0, v1) -> None:
         """Attach the platform virtual-clock endpoints to the span."""
@@ -84,10 +120,16 @@ class Span:
             self.args["virt1"] = float(v1)
 
     def __enter__(self) -> "Span":
-        self.t0, self._seq0 = self._tracer._open(self)
+        # the clock is read right outside the annotation's edges, with no
+        # allocation in between that could start a garbage collection
+        self._tracer._open(self)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._tracer._close(self)
         return False
 
@@ -118,14 +160,24 @@ _NULL_SPAN = _NullSpan()
 class Tracer:
     """Thread-safe span collector with Chrome trace-event export."""
 
+    #: How many of the newest spans a disabled tracer keeps for
+    #: :meth:`spans_in` while profiler sessions run; a benchmark's traced
+    #: window holds a few thousand, and a process profiled for hours holds
+    #: no more than this.
+    SESSION_SPANS = 1 << 14
+
     def __init__(self, enabled: bool = True):
         self.enabled = bool(enabled)
         self._epoch = time.perf_counter()
         self._lock = threading.Lock()
         self._local = threading.local()
-        self._seq = 0
+        #: event order; ``next`` on it is atomic
+        self._seq = itertools.count(1)
         #: finished spans, in close order.
         self.spans: list[Span] = []
+        #: finished spans recorded only because a profiler session ran
+        #: (the tracer disabled): the newest, for :meth:`spans_in`.
+        self._session = collections.deque(maxlen=self.SESSION_SPANS)
         #: (name, track, cat, ts, seq, args) instant events.
         self.instants: list[tuple] = []
 
@@ -135,44 +187,59 @@ class Tracer:
         """Seconds since the tracer epoch (host wall clock)."""
         return time.perf_counter() - self._epoch
 
-    def _next_seq(self) -> int:
-        with self._lock:
-            self._seq += 1
-            return self._seq
-
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, track: str = "main", cat: str = "runtime",
              **args):
-        """Open a span as a context manager; no-op when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return Span(self, name, track, cat, dict(args))
+        """Open a span as a context manager: the shared no-op span unless
+        the tracer is enabled or a JAX profiler session runs; under a
+        session the span is also a ``TraceAnnotation`` of its name.
 
-    def _stack(self) -> list:
-        st = getattr(self._local, "stack", None)
-        if st is None:
-            st = self._local.stack = []
-        return st
+        The open and close paths make few Python calls: under a profiler
+        session with its Python tracer on, each call is recorded, and that
+        is most of what a recorded span costs."""
+        ann = _ANNOTATION or _annotation()
+        if ann is None or not ann.is_enabled():
+            if not self.enabled:
+                return _NULL_SPAN
+            return Span(self, name, track, cat, args)
+        return Span(self, name, track, cat, args, ann(name))
 
-    def _open(self, span: Span) -> tuple[float, int]:
-        self._stack().append(span)
-        return self.now(), self._next_seq()
+    def _open(self, span: Span) -> None:
+        try:
+            self._local.stack.append(span)
+        except AttributeError:
+            self._local.stack = [span]
+        span._seq0 = next(self._seq)
+        span.t0 = time.perf_counter() - self._epoch
 
     def _close(self, span: Span) -> None:
-        st = self._stack()
+        # perf_counter is monotonic, so t1 >= t0
+        span.t1 = time.perf_counter() - self._epoch
+        span._seq1 = next(self._seq)
+        st = getattr(self._local, "stack", None)
         if st and st[-1] is span:
             st.pop()
-        span.t1 = max(self.now(), span.t0)
-        span._seq1 = self._next_seq()
         with self._lock:
-            self.spans.append(span)
+            (self.spans if self.enabled else self._session).append(span)
 
     def current(self) -> Span | _NullSpan:
         """The innermost span open on *this* thread (the null span when
         none is, so callers may annotate unconditionally)."""
         st = getattr(self._local, "stack", None)
         return st[-1] if st else _NULL_SPAN
+
+    def spans_in(self, t0: float, t1: float) -> list[tuple]:
+        """``(name, track, start, end)`` of the finished spans lying wholly
+        inside ``[t0, t1]``, recorded or kept from a profiler session; all
+        four times in ``time.perf_counter`` seconds, so one offset maps
+        them onto a profile's clock."""
+        epoch = self._epoch
+        lo, hi = t0 - epoch, t1 - epoch
+        with self._lock:
+            return [(s.name, s.track, s.t0 + epoch, s.t1 + epoch)
+                    for s in itertools.chain(self.spans, self._session)
+                    if s.t0 >= lo and s.t1 <= hi]
 
     def add_span(self, name: str, track: str, t0: float, t1: float,
                  cat: str = "runtime", args: dict | None = None) -> None:
@@ -184,8 +251,8 @@ class Tracer:
         span = Span(self, name, track, cat, dict(args or {}))
         span.t0 = float(t0)
         span.t1 = max(float(t1), span.t0)
-        span._seq0 = self._next_seq()
-        span._seq1 = self._next_seq()
+        span._seq0 = next(self._seq)
+        span._seq1 = next(self._seq)
         with self._lock:
             self.spans.append(span)
 
@@ -194,7 +261,7 @@ class Tracer:
         """Record a point event (fault, shed, breaker/brownout move)."""
         if not self.enabled:
             return
-        ts, seq = self.now(), self._next_seq()
+        ts, seq = self.now(), next(self._seq)
         with self._lock:
             self.instants.append((name, track, cat, ts, seq, dict(args)))
 

@@ -26,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import compat
+from repro.obs.trace import default_tracer
 from repro.kernels.prng import normal_pair
 from .contracts import (
     COL,
@@ -325,17 +326,21 @@ def price_batch(tasks: Sequence[PricingTask], n_paths,
     magnitude buckets (max/min <= ``_RAGGED_RATIO``) before padding, so a
     64-path shard never simulates a co-batched task's million paths; the
     uniform-n hot paths (benchmark ladders, calibration) stay one launch.
+    Each bucket is a ``pricing.pack`` span (grouping and packing) and a
+    ``pricing.finalize`` span (the per-task moments) on the process tracer.
 
     Returns one :class:`PriceResult` per task, in input order.
     """
     tasks = list(tasks)
     ns = np.broadcast_to(np.asarray(n_paths, dtype=np.int64), (len(tasks),))
     results: list[PriceResult | None] = [None] * len(tasks)
+    span = default_tracer().span
     for _key, group in group_by_launch(tasks):
         for bucket in _ragged_buckets([int(ns[i]) for i, _ in group]):
-            sub = [group[k] for k in bucket]
-            batch = TaskBatch.from_tasks([t for _, t in sub])
-            n_act = np.asarray([ns[i] for i, _ in sub], dtype=np.uint32)
+            with span("pricing.pack", track="pricing", tasks=len(bucket)):
+                sub = [group[k] for k in bucket]
+                batch = TaskBatch.from_tasks([t for _, t in sub])
+                n_act = np.asarray([ns[i] for i, _ in sub], dtype=np.uint32)
             if backend == "pallas":
                 from repro.kernels import ops  # local import: kernels are optional
 
@@ -347,8 +352,9 @@ def price_batch(tasks: Sequence[PricingTask], n_paths,
                                            jnp.int32(n_chunks),
                                            jnp.uint32(seed),
                                            chunk_paths=CHUNK_PATHS)
-            for k, (i, t) in enumerate(sub):
-                results[i] = _finalize(t, sums[k], sqs[k], int(ns[i]))
+            with span("pricing.finalize", track="pricing", tasks=len(sub)):
+                for k, (i, t) in enumerate(sub):
+                    results[i] = _finalize(t, sums[k], sqs[k], int(ns[i]))
     return results  # type: ignore[return-value]
 
 

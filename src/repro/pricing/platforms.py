@@ -44,6 +44,7 @@ from repro.core.metrics import (
     fit_accuracy_model,
     fit_latency_model,
 )
+from repro.obs.trace import default_tracer
 from repro.runtime.domain import PlatformSpec, local_device_spec
 from repro.runtime.scenario import Scenario, apply_scenario, salvage_runs
 from .contracts import Heston, PricingTask, group_by_launch
@@ -150,20 +151,35 @@ class LocalJaxPlatform:
         path counts, so per-platform latency totals (and hence measured
         makespans) are preserved while per-task betas reflect the *batched*
         throughput — the number production allocation actually sees.
+
+        Spans (on the process tracer): ``pricing.run_batch`` around the
+        call; inside it ``pricing.warm`` (the discarded launch and its
+        drain), ``pricing.launch`` (the timed one and its sync) and
+        ``pricing.records`` (the reads to the host).
         """
         ns = _as_path_list(tasks, n_paths)
-        warm = mc.price_batch(tasks, ns, seed=seed, backend=self.backend)
-        for r in warm:  # drain async dispatch so it cannot leak into t0
-            r.price.block_until_ready()
-        t0 = time.perf_counter()
-        results = mc.price_batch(tasks, ns, seed=seed, backend=self.backend)
-        for r in results:
-            r.price.block_until_ready()
-        latency = time.perf_counter() - t0
-        total = max(sum(ns), 1)
-        return [RunRecord(self.spec.name, t.task_id, n,
-                          float(r.price), float(r.ci95), latency * n / total)
-                for t, n, r in zip(tasks, ns, results)]
+        span = default_tracer().span
+        with span("pricing.run_batch", track="pricing", tasks=len(ns),
+                  paths=sum(ns)):
+            with span("pricing.warm", track="pricing"):
+                warm = mc.price_batch(tasks, ns, seed=seed,
+                                      backend=self.backend)
+                # drain async dispatch so it cannot leak into t0
+                for r in warm:
+                    r.price.block_until_ready()
+            with span("pricing.launch", track="pricing"):
+                t0 = time.perf_counter()
+                results = mc.price_batch(tasks, ns, seed=seed,
+                                         backend=self.backend)
+                for r in results:
+                    r.price.block_until_ready()
+                latency = time.perf_counter() - t0
+            total = max(sum(ns), 1)
+            with span("pricing.records", track="pricing"):
+                return [RunRecord(self.spec.name, t.task_id, n,
+                                  float(r.price), float(r.ci95),
+                                  latency * n / total)
+                        for t, n, r in zip(tasks, ns, results)]
 
     def run(self, task: PricingTask, n_paths: int, seed: int = 0) -> RunRecord:
         return self.run_batch([task], [n_paths], seed=seed)[0]

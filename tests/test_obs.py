@@ -14,6 +14,10 @@ Three tiers:
 * **Parity** — the concurrent and sequential executors produce bitwise
   identical span/instant multisets (wall-clock args excluded) under the
   canonical PR 6 fault storm.
+* **Profiler** — under a JAX profiler session the process tracer records
+  its spans and they land on the profile's host plane, on one clock with
+  ``spans_in``; with no session and ``REPRO_TRACE`` unset a span site is
+  the shared null span.
 """
 import json
 import math
@@ -41,7 +45,8 @@ from repro.obs import (
     resolve_tracer,
     validate_chrome_trace,
 )
-from repro.obs.trace import PHASE_KEYS
+from repro.obs.trace import (PHASE_KEYS, _NULL_SPAN, default_tracer,
+                             set_default_tracer)
 from repro.runtime import (
     OnlineConfig,
     OnlineScheduler,
@@ -393,3 +398,117 @@ def test_all_shed_open_loop_round_reports_through_ledger():
     sheds = [e for e in events if e["ph"] == "i"
              and e["name"].startswith("shed:")]
     assert sheds and all(e["tid"] for e in sheds)
+
+
+# ------------------------------------------------------------ profiler tier
+
+
+@pytest.fixture
+def process_tracer(monkeypatch):
+    """A fresh process tracer, with ``REPRO_TRACE`` unset; the previous
+    one is put back after the test."""
+    import repro.obs.trace as trace_mod
+
+    monkeypatch.delenv("REPRO_TRACE", raising=False)
+    previous = trace_mod._DEFAULT
+    set_default_tracer(None)
+    yield default_tracer()
+    set_default_tracer(previous)
+
+
+def _host_events(trace_dir) -> dict:
+    """name -> [(start_ns, end_ns)] of every event on the profile's host
+    planes."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def test_span_records_under_profiler_session_on_one_clock(process_tracer,
+                                                          tmp_path):
+    import time
+
+    import jax
+
+    assert not process_tracer.enabled
+    with jax.profiler.trace(str(tmp_path)):
+        # the anchor: a perf_counter reading, then at once an annotation,
+        # as the benchmark takes its window's start
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("test.anchor"):
+            with process_tracer.span("test.program", track="t", rows=3):
+                time.sleep(0.002)
+        t1 = time.perf_counter()
+    (name, track, start, end), = process_tracer.spans_in(t0, t1)
+    assert (name, track) == ("test.program", "t")
+    assert t0 <= start < end <= t1 and end - start >= 0.002
+    # a disabled tracer keeps session spans for spans_in, not for export
+    assert process_tracer.spans == []
+    assert process_tracer._session[0].args == {"rows": 3}
+    host = _host_events(tmp_path)
+    (anchor, _), = host["test.anchor"]
+    (s_ns, e_ns), = host["test.program"]
+    offset = anchor - t0 * 1e9
+    assert abs(start * 1e9 + offset - s_ns) < 1e6
+    assert abs(end * 1e9 + offset - e_ns) < 1e6
+    # the session is over: the span site is off again
+    assert process_tracer.span("test.program") is _NULL_SPAN
+
+
+def test_span_site_is_null_with_no_session_and_no_env(process_tracer):
+    import jax  # noqa: F401  (the session check runs once JAX is imported)
+
+    sp = process_tracer.span("test.off", track="t", rows=1)
+    assert sp is _NULL_SPAN
+    with sp:
+        pass
+    assert process_tracer.spans == []
+    assert process_tracer.spans_in(0.0, float("inf")) == []
+
+
+def test_spans_in_keeps_only_spans_wholly_inside_the_window():
+    import time
+
+    t = Tracer()
+    t0 = time.perf_counter()
+    with t.span("a"):
+        with t.span("b"):
+            pass
+    t_mid = time.perf_counter()
+    with t.span("c"):
+        pass
+    t1 = time.perf_counter()
+    assert [s[0] for s in t.spans_in(t0, t1)] == ["b", "a", "c"]
+    assert [s[0] for s in t.spans_in(t_mid, t1)] == ["c"]
+    for name, track, start, end in t.spans_in(t0, t1):
+        assert track == "main" and t0 <= start <= end <= t1
+
+
+def test_disabled_tracer_keeps_a_bounded_tail_of_session_spans(
+        process_tracer, tmp_path, monkeypatch):
+    import jax
+
+    monkeypatch.setattr(Tracer, "SESSION_SPANS", 4)
+    t = Tracer(enabled=False)
+    with jax.profiler.trace(str(tmp_path)):
+        for i in range(10):
+            with t.span(f"s{i}"):
+                pass
+    # after the session a span site records nothing more
+    with t.span("after"):
+        pass
+    assert t.spans == []
+    assert [s[0] for s in t.spans_in(0.0, float("inf"))] == [
+        "s6", "s7", "s8", "s9"]
