@@ -229,15 +229,18 @@ class Tracer:
         st = getattr(self._local, "stack", None)
         return st[-1] if st else _NULL_SPAN
 
-    def spans_in(self, t0: float, t1: float) -> list[tuple]:
+    def spans_in(self, t0: float, t1: float,
+                 args: bool = False) -> list[tuple]:
         """``(name, track, start, end)`` of the finished spans lying wholly
         inside ``[t0, t1]``, recorded or kept from a profiler session; all
         four times in ``time.perf_counter`` seconds, so one offset maps
-        them onto a profile's clock."""
+        them onto a profile's clock. With ``args``, each tuple also ends
+        in a copy of the span's attributes."""
         epoch = self._epoch
         lo, hi = t0 - epoch, t1 - epoch
         with self._lock:
             return [(s.name, s.track, s.t0 + epoch, s.t1 + epoch)
+                    + ((dict(s.args),) if args else ())
                     for s in itertools.chain(self.spans, self._session)
                     if s.t0 >= lo and s.t1 <= hi]
 

@@ -178,3 +178,74 @@ def test_execute_batches_per_platform_family():
     assert set(report.prices) == {t.task_id for t in tasks}
     assert report.measured_makespan > 0
     assert all(np.isfinite(list(report.prices.values())))
+
+
+def _tap_launches(monkeypatch) -> list[int]:
+    """Tap the platform's launch function, ``mc.price_batch``: one entry a
+    launch, the engine traces (jit cache misses) the launch added."""
+    grew: list[int] = []
+    launch = mc.price_batch
+
+    def tapped(*a, **kw):
+        before = sum(mc.trace_counts().values())
+        out = launch(*a, **kw)
+        grew.append(sum(mc.trace_counts().values()) - before)
+        return out
+
+    monkeypatch.setattr(mc, "price_batch", tapped)
+    return grew
+
+
+def _records(recs):
+    return [(r.task_id, r.n_paths, r.price, r.ci95) for r in recs]
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_local_platform_warms_a_launch_shape_once(backend, monkeypatch):
+    """The first call of a launch shape runs a discarded warm launch and
+    then the timed one; a repeat runs the timed launch alone, compiles
+    nothing inside it, and returns the same records to the bit."""
+    tasks = table1_workload(seed=27, n_steps=8,
+                            categories=[("BS-A", 2), ("H-A", 1)])
+    ns = [256, 512, 256]
+    grew = _tap_launches(monkeypatch)
+    plat = LocalJaxPlatform(backend=backend)
+    warmed = plat.run_batch(tasks, ns, seed=4)
+    assert len(grew) == 2 and grew[1] == 0  # warm, then a cached timed launch
+    assert (plat.warm_launches, plat.warm_skips) == (1, 0)
+
+    skipped = plat.run_batch(tasks, ns, seed=4)
+    assert grew[2:] == [0]  # the timed launch alone, nothing compiled in it
+    assert (plat.warm_launches, plat.warm_skips) == (1, 1)
+    assert _records(skipped) == _records(warmed)
+    assert all(r.latency > 0 for r in skipped)
+
+    fresh = LocalJaxPlatform(backend=backend)
+    assert _records(fresh.run_batch(tasks, ns, seed=4)) == _records(warmed)
+    assert len(grew) == 5 and (fresh.warm_launches, fresh.warm_skips) == (1, 0)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("change,warms", [
+    ("paths", True),    # another path count: a key not yet warmed
+    ("model", True),    # another launch group: another executable
+    ("payoff", False),  # same launch key: payoff is a runtime code
+])
+def test_local_platform_warms_again_for_a_new_key(backend, change, warms,
+                                                 monkeypatch):
+    first = table1_workload(seed=28, n_steps=8, categories=[("BS-A", 2)])
+    ns = [256, 256]
+    plat = LocalJaxPlatform(backend=backend)
+    plat.run_batch(first, ns, seed=6)
+    tasks = {"paths": first,
+             "model": table1_workload(seed=28, n_steps=8,
+                                      categories=[("H-A", 2)]),
+             "payoff": table1_workload(seed=28, n_steps=8,
+                                       categories=[("BS-B", 2)])}[change]
+    if change == "paths":
+        ns = [256, 4096]
+    grew = _tap_launches(monkeypatch)
+    recs = plat.run_batch(tasks, ns, seed=6)
+    assert len(grew) == 1 + warms and grew[-1] == 0
+    assert (plat.warm_launches, plat.warm_skips) == (1 + warms, 1 - warms)
+    assert [r.n_paths for r in recs] == ns

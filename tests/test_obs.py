@@ -487,13 +487,15 @@ def test_spans_in_keeps_only_spans_wholly_inside_the_window():
         with t.span("b"):
             pass
     t_mid = time.perf_counter()
-    with t.span("c"):
+    with t.span("c", rows=2):
         pass
     t1 = time.perf_counter()
     assert [s[0] for s in t.spans_in(t0, t1)] == ["b", "a", "c"]
     assert [s[0] for s in t.spans_in(t_mid, t1)] == ["c"]
     for name, track, start, end in t.spans_in(t0, t1):
         assert track == "main" and t0 <= start <= end <= t1
+    assert [(s[0], s[4]) for s in t.spans_in(t0, t1, args=True)] == [
+        ("b", {}), ("a", {}), ("c", {"rows": 2})]
 
 
 def test_disabled_tracer_keeps_a_bounded_tail_of_session_spans(

@@ -35,7 +35,7 @@ def _recorded(tracer, trace_dir, fn):
     t0 = time.perf_counter()
     with jax.profiler.trace(str(trace_dir)):
         out = fn()
-    return out, tracer.spans_in(t0, time.perf_counter())
+    return out, tracer.spans_in(t0, time.perf_counter(), args=True)
 
 
 def _inside(spans, outer):
@@ -78,26 +78,34 @@ def test_pricing_run_batch_spans_under_a_session(process_tracer, tmp_path):
     tasks = table1_workload(seed=3, n_steps=8,
                             categories=[("BS-A", 2), ("H-A", 2)])
     assert len(tasks) == 4
-    plat = LocalJaxPlatform(backend="jnp")
     paths = [512, 1024, 512, 2048]
-    plain = plat.run_batch(tasks, paths, seed=5)
-    traced, spans = _recorded(process_tracer, tmp_path,
-                              lambda: plat.run_batch(tasks, paths, seed=5))
-    assert [(r.task_id, r.price, r.ci95) for r in plain] == \
-        [(r.task_id, r.price, r.ci95) for r in traced]
+    plain = LocalJaxPlatform(backend="jnp").run_batch(tasks, paths, seed=5)
+    plat = LocalJaxPlatform(backend="jnp")
+    traced, spans = _recorded(process_tracer, tmp_path, lambda: [
+        plat.run_batch(tasks, paths, seed=5) for _ in range(2)])
+    for recs in traced:
+        assert [(r.task_id, r.price, r.ci95) for r in plain] == \
+            [(r.task_id, r.price, r.ci95) for r in recs]
 
-    n = collections.Counter(s[0] for s in spans)
-    for name in ("pricing.run_batch", "pricing.warm", "pricing.launch",
-                 "pricing.records"):
-        assert n[name] == 1, name
-    # two launch groups (Black-Scholes, Heston), each priced twice
-    assert n["pricing.pack"] == n["pricing.finalize"] == 4
-    top, = [s for s in spans if s[0] == "pricing.run_batch"]
-    assert len(_inside(spans, top)) == len(spans) - 1
-    for phase in ("pricing.warm", "pricing.launch"):
-        span, = [s for s in spans if s[0] == phase]
-        held = collections.Counter(s[0] for s in _inside(spans, span))
-        assert held == {"pricing.pack": 2, "pricing.finalize": 2}
+    tops = [s for s in spans if s[0] == "pricing.run_batch"]
+    assert len(tops) == 2
+    assert sum(len(_inside(spans, top)) for top in tops) == len(spans) - 2
+    # the first call warms its launch shapes, the repeat goes straight to
+    # the timed launch; two launch groups (Black-Scholes, Heston) a launch
+    for top, launched in zip(sorted(tops, key=lambda s: s[2]), (1, 0)):
+        inner = _inside(spans, top)
+        n = collections.Counter(s[0] for s in inner)
+        for name in ("pricing.warm", "pricing.launch", "pricing.records"):
+            assert n[name] == 1, name
+        assert n["pricing.pack"] == n["pricing.finalize"] == 2 + 2 * launched
+        warm, = [s for s in inner if s[0] == "pricing.warm"]
+        assert warm[4] == {"launched": launched}
+        launch, = [s for s in inner if s[0] == "pricing.launch"]
+        for phase, held in ((warm, launched), (launch, 1)):
+            got = collections.Counter(s[0] for s in _inside(spans, phase))
+            assert got == ({"pricing.pack": 2, "pricing.finalize": 2}
+                           if held else {})
+    assert (plat.warm_launches, plat.warm_skips) == (1, 1)
 
 
 def test_lm_platform_run_batch_span_holds_the_engine_call(process_tracer,
