@@ -75,7 +75,8 @@ class ServeEngine:
         if tp > 1:
             from jax.sharding import NamedSharding
 
-            from repro.launch.tp import build_tp_step_fns, tp_param_specs, validate_tp
+            from repro.launch.tp import (build_tp_step_fns, exchange_counts,
+                                         tp_param_specs, validate_tp)
 
             validate_tp(cfg, tp)
             specs = tp_param_specs(jax.eval_shape(self.model.init, key),
@@ -89,6 +90,8 @@ class ServeEngine:
                                                 self.max_seq)
             self._prefill = jax.jit(prefill)
             self._decode = jax.jit(decode)
+            pre, dec = exchange_counts(prefill, decode, self.params,
+                                       self._batch_inputs(seed))
         else:
             self.params = jax.jit(self.model.init)(key)
 
@@ -98,6 +101,13 @@ class ServeEngine:
             # a named function, so the profile shows ``jit_prefill``
             self._prefill = jax.jit(prefill)
             self._decode = jax.jit(self.model.decode_step)
+            pre = dec = {"collectives": 0, "exchange_bytes": 0}
+        self.tp = tp
+        #: the attributes of every ``serve.prefill`` / ``serve.decode``
+        #: span: the mesh width and the exchange one call makes
+        #: (:func:`repro.launch.tp.exchange_counts`)
+        self._prefill_args = {"tp": tp, **pre}
+        self._decode_args = {"tp": tp, **dec}
         self._warm = False
 
     def probe_logits(self, seed: int = 0, steps: int = 1, tokens=None):
@@ -213,7 +223,9 @@ class ServeEngine:
         Spans (on the process tracer): ``serve.generate`` around the call,
         ``serve.prefill``, one ``serve.decode`` a step, and inside them
         ``serve.wait`` (the device sync) and ``serve.fetch`` (the token's
-        copy to the host).
+        copy to the host). ``serve.generate`` carries the mesh width
+        ``tp``; ``serve.prefill`` and ``serve.decode`` carry ``tp`` and
+        the call's ``collectives`` and ``exchange_bytes`` (0 at tp=1).
         """
         import jax.numpy as jnp
         import numpy as np
@@ -232,8 +244,9 @@ class ServeEngine:
         span = default_tracer().span
 
         with span("serve.generate", track="serve", streams=len(gens),
-                  steps=max(gens)):
-            with span("serve.prefill", track="serve", rows=self.batch):
+                  steps=max(gens), tp=self.tp):
+            with span("serve.prefill", track="serve", rows=self.batch,
+                      **self._prefill_args):
                 t0 = time.perf_counter()
                 cache, logits = self._prefill(self.params, batch)
                 with span("serve.wait", track="serve"):
@@ -244,7 +257,7 @@ class ServeEngine:
             generated = [np.asarray(toks)]
             per_stream: list[list[float]] = [[] for _ in gens]
             for step in range(max(gens)):
-                with span("serve.decode", track="serve"):
+                with span("serve.decode", track="serve", **self._decode_args):
                     t0 = time.perf_counter()
                     cache, logits = self._decode(self.params, cache, toks)
                     toks = jnp.argmax(logits[:, -1:],

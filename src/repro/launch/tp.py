@@ -34,7 +34,7 @@ from repro.compat import shard_map
 from repro.models.layers import attention, rmsnorm, rope
 
 __all__ = ["TPShardingError", "tp_param_specs", "tp_cache_specs",
-           "build_tp_step_fns", "validate_tp"]
+           "build_tp_step_fns", "validate_tp", "exchange_counts"]
 
 MODEL = "model"
 
@@ -174,7 +174,54 @@ def build_tp_step_fns(model, pspecs: dict, mesh, max_seq: int):
                           in_specs=(pspecs, cache_spec, P(None, None)),
                           out_specs=out_specs, axis_names={MODEL})
 
+    # named functions, so the profile shows ``jit_prefill`` and
+    # ``jit_decode_step`` as on one device
     def prefill(params, batch):
         return sm_prefill(params, batch["tokens"])
 
-    return prefill, sm_decode
+    def decode_step(params, cache, tokens):
+        return sm_decode(params, cache, tokens)
+
+    return prefill, decode_step
+
+
+def _gathers(jaxpr, times: int = 1):
+    """(executions a call, equation) of every all-gather in ``jaxpr`` and
+    the programs nested in it; a scan's body runs ``length`` times."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "all_gather":
+            yield times, eqn
+        reps = times
+        if eqn.primitive.name == "scan":
+            reps *= eqn.params["length"]
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _gathers(sub, reps)
+
+
+def _counts(step, *args):
+    """(counters of one call of ``step`` on ``args``, its output shapes)."""
+    closed, out = jax.make_jaxpr(step, return_shape=True)(*args)
+    n = received = 0
+    for times, eqn in _gathers(closed.jaxpr):
+        tp = eqn.params["axis_size"]
+        aval = eqn.outvars[0].aval
+        n += times
+        # a device holds 1/tp of the gathered tensor and receives the rest
+        received += times * aval.size * aval.dtype.itemsize * (tp - 1) // tp
+    return {"collectives": n, "exchange_bytes": received}, out
+
+
+def exchange_counts(prefill, decode, params, batch) -> tuple[dict, dict]:
+    """Counters of the exchange for one ``prefill`` call on ``batch`` and
+    one ``decode`` step after it (:func:`build_tp_step_fns`' pair):
+    ``collectives``, the all-gathers the call makes (2 a layer and one at
+    the logits), and ``exchange_bytes``, the bytes one device receives in
+    them. Read from the steps' traced programs, so they follow the shapes
+    each step gathers; nothing is compiled or run."""
+    pre, (cache, logits) = _counts(prefill, params, batch)
+    tokens = jax.ShapeDtypeStruct((logits.shape[0], 1), jnp.int32)
+    dec, _ = _counts(decode, params, cache, tokens)
+    return pre, dec

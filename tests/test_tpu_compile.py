@@ -136,3 +136,45 @@ def test_yi_9b_tp4_init_and_prefill_fit_four_v5e_chips(topo):
     compiled = jax.jit(prefill).lower(params, {"tokens": tokens}).compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES
     assert "all-gather" in compiled.as_text()
+
+
+def test_yi_9b_tp4_decode_step_compiles_with_its_exchange(topo):
+    """The Yi-9B tp=4 decode step at the benchmark cell's shapes (batch 32,
+    cache 161 + 1,024 + 8) compiles as ``jit_decode_step``, fits each
+    device, and keeps its all-gathers; the engine's counters for it are
+    97 all-gathers (two a layer, one at the logits) and 3/4 of each
+    gathered tensor received."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.launch.tp import (build_tp_step_fns, exchange_counts,
+                                 tp_param_specs)
+    from repro.models import build_model
+
+    cfg = get_config("yi_9b")
+    model = build_model(cfg)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    specs = tp_param_specs(shapes, model.block_key)
+    params = {k: jax.ShapeDtypeStruct(
+        v.shape, v.dtype, sharding=NamedSharding(mesh, specs[k]))
+        for k, v in shapes.items()}
+    replicated = NamedSharding(mesh, P())
+    batch = {"tokens": jax.ShapeDtypeStruct((32, 161), jnp.int32,
+                                            sharding=replicated)}
+    prefill, decode = build_tp_step_fns(model, specs, mesh, max_seq=1193)
+    cache, _ = jax.eval_shape(prefill, params, batch)
+    tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=replicated)
+    compiled = jax.jit(decode).lower(params, cache, tokens).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_decode_step")
+    assert "all-gather" in text
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+    _, dec = exchange_counts(prefill, decode, params, batch)
+    gathered = cfg.n_layers * 32 * (cfg.n_heads * cfg.hd + cfg.d_ff) \
+        + 32 * cfg.vocab
+    assert dec == {"collectives": 2 * cfg.n_layers + 1,
+                   "exchange_bytes": gathered * 2 * 3 // 4}
