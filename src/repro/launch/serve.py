@@ -44,7 +44,9 @@ class ServeEngine:
     ``prompt_len + gen <= max_seq`` reuses the same two executables —
     the engine analogue of the pricing engine's runtime-parameter batching
     (the compile unit is the (config, batch, max_seq) family, not the
-    individual request).
+    individual request). The decode step donates its cache argument: a
+    cache passed to ``_decode`` is consumed, and only the one it returns
+    lives on.
     """
 
     def __init__(self, cfg, batch: int, prompt_len: int, max_seq: int | None = None,
@@ -89,7 +91,7 @@ class ServeEngine:
             prefill, decode = build_tp_step_fns(self.model, specs, mesh,
                                                 self.max_seq)
             self._prefill = jax.jit(prefill)
-            self._decode = jax.jit(decode)
+            self._decode = jax.jit(decode, donate_argnums=(1,))
             pre, dec = exchange_counts(prefill, decode, self.params,
                                        self._batch_inputs(seed))
         else:
@@ -100,12 +102,13 @@ class ServeEngine:
 
             # a named function, so the profile shows ``jit_prefill``
             self._prefill = jax.jit(prefill)
-            self._decode = jax.jit(self.model.decode_step)
+            self._decode = jax.jit(self.model.decode_step, donate_argnums=(1,))
             pre = dec = {"collectives": 0, "exchange_bytes": 0}
         self.tp = tp
         #: the attributes of every ``serve.prefill`` / ``serve.decode``
         #: span: the mesh width and the exchange one call makes
-        #: (:func:`repro.launch.tp.exchange_counts`)
+        #: (:func:`repro.launch.tp.exchange_counts`); ``warm`` adds
+        #: ``cache_in_place`` to the decode step's
         self._prefill_args = {"tp": tp, **pre}
         self._decode_args = {"tp": tp, **dec}
         self._warm = False
@@ -163,9 +166,13 @@ class ServeEngine:
 
     def warm(self, seed: int = 0) -> None:
         """Compile prefill + decode outside any timed region (the paper's
-        gamma measures dispatch, not code generation)."""
+        gamma measures dispatch, not code generation). Records on the
+        decode spans' attributes whether the step consumed every buffer
+        of the cache it was given (``cache_in_place`` 1: the donated
+        cache was taken, so the step updates it in place)."""
         if self._warm:
             return
+        import jax
         import jax.numpy as jnp
 
         batch = self._batch_inputs(seed)
@@ -173,6 +180,8 @@ class ServeEngine:
         toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         _, logits = self._decode(self.params, cache, toks)
         logits.block_until_ready()
+        self._decode_args["cache_in_place"] = int(all(
+            leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(cache)))
         self._warm = True
 
     def generate(self, gen: int, seed: int = 0) -> GenerationResult:
@@ -225,7 +234,8 @@ class ServeEngine:
         ``serve.wait`` (the device sync) and ``serve.fetch`` (the token's
         copy to the host). ``serve.generate`` carries the mesh width
         ``tp``; ``serve.prefill`` and ``serve.decode`` carry ``tp`` and
-        the call's ``collectives`` and ``exchange_bytes`` (0 at tp=1).
+        the call's ``collectives`` and ``exchange_bytes`` (0 at tp=1);
+        ``serve.decode`` also ``cache_in_place`` (see :meth:`warm`).
         """
         import jax.numpy as jnp
         import numpy as np

@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
-from repro.models.layers import attention, rmsnorm, rope
+from repro.models.layers import (append_kv, attention, prefill_cache,
+                                 rmsnorm, rope)
 
 __all__ = ["TPShardingError", "tp_param_specs", "tp_cache_specs",
            "build_tp_step_fns", "validate_tp", "exchange_counts"]
@@ -85,65 +86,94 @@ def tp_param_specs(params: dict, block_key: str = "blocks") -> dict:
 
 
 def tp_cache_specs() -> dict:
-    """KV cache [L, B, S, KVH, D] shards on the kv-head axis."""
-    kv = P(None, None, None, MODEL, None)
+    """KV cache [L, S, KVH, B, D] (``DenseModel.cache_shape``'s order)
+    shards on the kv-head axis."""
+    kv = P(None, None, MODEL, None, None)
     return {"k": kv, "v": kv, "pos": P()}
 
 
-def _tp_forward(cfg, block_key: str):
-    """Per-device worker: the DenseModel forward with gathers at the two
-    contraction-sharded matmuls (attention out-proj, MLP down-proj) and
-    at the logits. Mirrors transformer.apply_block exactly elsewhere."""
+def _tp_forward(cfg, block_key: str, max_seq: int):
+    """Per-device workers (prefill, decode): the DenseModel forward with
+    gathers at the two contraction-sharded matmuls (attention out-proj,
+    MLP down-proj) and at the logits. Mirrors transformer.apply_block
+    exactly elsewhere; the cache goes as in ``DenseModel.prefill`` and
+    ``decode_step``."""
     eps, theta = cfg.eps, cfg.rope_theta
+    pre = block_key + "/"
 
-    def fwd(p, cache, tokens, last_only):
+    def layer(lp, h, positions, attend):
+        """One block; ``attend(q, k, v)`` -> (attention output, what the
+        layer keeps of the cache)."""
+        xn = rmsnorm(h, lp["ln1"], eps)
+        q = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wq"])
+        k = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wk"])
+        v = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wv"])
+        if "attn/bq" in lp:
+            q = q + lp["attn/bq"]
+            k = k + lp["attn/bk"]
+            v = v + lp["attn/bv"]
+        q = rope(q, positions, theta)
+        k = rope(k, positions, theta)
+        out, kept = attend(q, k, v)
+        out = jax.lax.all_gather(out, MODEL, axis=2, tiled=True)
+        h = h + jnp.einsum("bshk,hkd->bsd", out, lp["attn/wo"])
+        xn = rmsnorm(h, lp["ln2"], eps)
+        hid = xn @ lp["mlp/w_in"]
+        if cfg.mlp_variant == "swiglu":
+            hid = jax.nn.silu(xn @ lp["mlp/w_gate"]) * hid
+        elif cfg.mlp_variant == "geglu":
+            hid = jax.nn.gelu(xn @ lp["mlp/w_gate"]) * hid
+        else:
+            hid = jax.nn.gelu(hid)
+        hid = jax.lax.all_gather(hid, MODEL, axis=2, tiled=True)
+        return h + hid @ lp["mlp/w_out"], kept
+
+    def logits(p, x):
+        x = rmsnorm(x, p["ln_f"], eps)
+        out = jax.lax.all_gather(x @ p["unembed"], MODEL, axis=2, tiled=True)
+        return out.astype(jnp.float32)
+
+    def blocks(p):
+        return {k[len(pre):]: v for k, v in p.items() if k.startswith(pre)}
+
+    def prefill(p, tokens):
+        x = p["embed"][tokens].astype(cfg.cdtype)
+        positions = jnp.arange(x.shape[1])
+
+        def attend(q, k, v):
+            return attention(q, k, v, causal=True), (k, v)
+
+        def body(h, lp):
+            return layer(lp, h, positions, attend)
+
+        x, (ks, vs) = jax.lax.scan(body, x, blocks(p))
+        return (prefill_cache(ks, vs, max_seq, cfg.pdtype),
+                logits(p, x[:, -1:]))
+
+    def decode(p, cache, tokens):
         x = p["embed"][tokens].astype(cfg.cdtype)
         pos0 = cache["pos"]
         positions = pos0 + jnp.arange(x.shape[1])
-        pre = block_key + "/"
-        blocks = {k[len(pre):]: v for k, v in p.items() if k.startswith(pre)}
 
-        def body(h, xs):
-            lp, k_l, v_l = xs
-            xn = rmsnorm(h, lp["ln1"], eps)
-            q = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wq"])
-            k = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wk"])
-            v = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wv"])
-            if "attn/bq" in lp:
-                q = q + lp["attn/bq"]
-                k = k + lp["attn/bk"]
-                v = v + lp["attn/bv"]
-            q = rope(q, positions, theta)
-            k = rope(k, positions, theta)
-            kc = jax.lax.dynamic_update_slice(k_l, k.astype(k_l.dtype),
-                                              (0, pos0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(v_l, v.astype(v_l.dtype),
-                                              (0, pos0, 0, 0))
-            out = attention(q, kc, vc, causal=True, q_offset=pos0)
-            out = jax.lax.all_gather(out, MODEL, axis=2, tiled=True)
-            h = h + jnp.einsum("bshk,hkd->bsd", out, lp["attn/wo"])
-            xn = rmsnorm(h, lp["ln2"], eps)
-            hid = xn @ lp["mlp/w_in"]
-            if cfg.mlp_variant == "swiglu":
-                hid = jax.nn.silu(xn @ lp["mlp/w_gate"]) * hid
-            elif cfg.mlp_variant == "geglu":
-                hid = jax.nn.gelu(xn @ lp["mlp/w_gate"]) * hid
-            else:
-                hid = jax.nn.gelu(hid)
-            hid = jax.lax.all_gather(hid, MODEL, axis=2, tiled=True)
-            h = h + hid @ lp["mlp/w_out"]
-            return h, (kc, vc)
+        def body(carry, xs):
+            h, kc, vc = carry
+            i, lp = xs
 
-        x, (ks, vs) = jax.lax.scan(body, x, (blocks, cache["k"], cache["v"]))
-        new_cache = {"k": ks, "v": vs, "pos": pos0 + tokens.shape[1]}
-        if last_only:
-            x = x[:, -1:]
-        x = rmsnorm(x, p["ln_f"], eps)
-        logits = x @ p["unembed"]
-        logits = jax.lax.all_gather(logits, MODEL, axis=2, tiled=True)
-        return new_cache, logits.astype(jnp.float32)
+            def attend(q, k, v):
+                c, k, v = append_kv({"k": kc, "v": vc, "pos": pos0}, k, v, i)
+                return (attention(q, k, v, causal=True, q_offset=pos0),
+                        (c["k"], c["v"]))
 
-    return fwd
+            h, (kc, vc) = layer(lp, h, positions, attend)
+            return (h, kc, vc), None
+
+        (x, ks, vs), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (jnp.arange(cfg.n_layers), blocks(p)))
+        return ({"k": ks, "v": vs, "pos": pos0 + tokens.shape[1]},
+                logits(p, x))
+
+    return prefill, decode
 
 
 def build_tp_step_fns(model, pspecs: dict, mesh, max_seq: int):
@@ -154,23 +184,14 @@ def build_tp_step_fns(model, pspecs: dict, mesh, max_seq: int):
     cfg = model.cfg
     tp = mesh.shape[MODEL]
     validate_tp(cfg, tp)
-    fwd = _tp_forward(cfg, model.block_key)
+    prefill_worker, decode_worker = _tp_forward(cfg, model.block_key, max_seq)
     cache_spec = tp_cache_specs()
     out_specs = (cache_spec, P(None, None, None))
-    kvh_local = cfg.n_kv_heads // tp
-
-    def prefill_worker(p, tokens):
-        b = tokens.shape[0]
-        shape = (cfg.n_layers, b, max_seq, kvh_local, cfg.hd)
-        cache = {"k": jnp.zeros(shape, cfg.pdtype),
-                 "v": jnp.zeros(shape, cfg.pdtype),
-                 "pos": jnp.asarray(0, jnp.int32)}
-        return fwd(p, cache, tokens, True)
 
     sm_prefill = shard_map(prefill_worker, mesh,
                            in_specs=(pspecs, P(None, None)),
                            out_specs=out_specs, axis_names={MODEL})
-    sm_decode = shard_map(lambda p, c, t: fwd(p, c, t, False), mesh,
+    sm_decode = shard_map(decode_worker, mesh,
                           in_specs=(pspecs, cache_spec, P(None, None)),
                           out_specs=out_specs, axis_names={MODEL})
 

@@ -17,7 +17,8 @@ from jax.sharding import PartitionSpec as P
 from .common import ParamBuilder, Rules
 
 __all__ = ["rmsnorm", "rope", "attention", "mlp", "init_attn", "init_mlp",
-           "cross_entropy", "apply_attn", "init_norm"]
+           "cross_entropy", "apply_attn", "init_norm", "append_kv",
+           "prefill_cache"]
 
 
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
@@ -146,17 +147,67 @@ def init_attn(b: ParamBuilder, cfg, rules: Rules, prefix: str = "attn") -> None:
         b.zeros(f"{prefix}/bv", (kvh, hd), P(kv_mdl, None))
 
 
+def _stored(kv: jnp.ndarray) -> jnp.ndarray:
+    """[B, S, KVH, D] -> [S, KVH, B, D], one layer of the stacked cache."""
+    return jnp.transpose(kv, (1, 2, 0, 3))
+
+
+def append_kv(cache: dict, k: jnp.ndarray, v: jnp.ndarray, layer=None):
+    """Write a call's keys and values ``k``, ``v`` [B, S, KVH, D] into
+    ``cache`` at ``cache["pos"]``; returns (the updated cache, the keys and
+    values the call attends over, [B, Smax, KVH, D]).
+
+    ``cache`` holds one layer's k/v [B, Smax, KVH, D] when ``layer`` is
+    None. Otherwise it is the serving cache of every layer, k/v
+    [L, Smax, KVH, B, D] (batch second-minor, the order the compiler lays
+    the decode step's carry out in), and only the S new positions of
+    ``layer`` are written: a donated cache is then updated in place.
+    """
+    pos = cache["pos"]
+    if layer is None:
+        ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
+                                          (0, pos, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
+                                          (0, pos, 0, 0))
+        k_att, v_att = ck, cv
+    else:
+        at = (layer, pos, 0, 0, 0)
+        ck = jax.lax.dynamic_update_slice(
+            cache["k"], _stored(k).astype(cache["k"].dtype)[None], at)
+        cv = jax.lax.dynamic_update_slice(
+            cache["v"], _stored(v).astype(cache["v"].dtype)[None], at)
+        k_att = jnp.transpose(ck[layer], (2, 0, 1, 3))
+        v_att = jnp.transpose(cv[layer], (2, 0, 1, 3))
+    return {"k": ck, "v": cv, "pos": pos + k.shape[1]}, k_att, v_att
+
+
+def prefill_cache(ks: jnp.ndarray, vs: jnp.ndarray, max_seq: int, dtype):
+    """The serving cache (:func:`append_kv`'s stacked order) of a prefill
+    from its layers' own keys and values ``ks``, ``vs`` [L, B, S, KVH, D]:
+    written once, padded to ``max_seq`` positions, at position S."""
+    s = ks.shape[2]
+    pad = ((0, 0), (0, max_seq - s), (0, 0), (0, 0), (0, 0))
+
+    def whole(x):
+        return jnp.pad(jnp.transpose(x, (0, 2, 3, 1, 4)).astype(dtype), pad)
+
+    return {"k": whole(ks), "v": whole(vs), "pos": jnp.asarray(s, jnp.int32)}
+
+
 def apply_attn(p: dict, cfg, x: jnp.ndarray, *, positions, cache=None,
-               window: int | None = None, q_chunk: int | None = None,
-               prefix: str = "attn", kv_override=None, use_rope: bool = True,
-               unroll: bool = False):
+               layer=None, window: int | None = None,
+               q_chunk: int | None = None, prefix: str = "attn",
+               kv_override=None, use_rope: bool = True, unroll: bool = False):
     """Full attention sub-block: qkv proj -> rope -> (cache) -> attn -> out.
 
-    cache: None (training/prefill without cache) or dict with keys
-    {"k": [B, Smax, KVH, D], "v": ..., "pos": scalar} — decode appends at
-    ``pos`` and attends over the first pos+Sq entries.
+    cache: None (training/prefill: attend over the call's own keys) or a
+    dict {"k", "v", "pos"} that decode appends to at ``pos`` and attends
+    over the first pos+Sq entries of: one layer's [B, Smax, KVH, D], or
+    with ``layer`` set the stacked serving cache (:func:`append_kv`).
     kv_override: (k, v) for cross-attention (keys from the encoder).
-    Returns (out, new_cache).
+    Returns (out, kv): with a cache, the updated cache; without one, the
+    call's own keys and values {"k", "v"} [B, S, KVH, D] (what a prefill
+    stores).
     """
     q = jnp.einsum("bsd,dhk->bshk", x, p[f"{prefix}/wq"])
     if f"{prefix}/bq" in p:
@@ -177,23 +228,18 @@ def apply_attn(p: dict, cfg, x: jnp.ndarray, *, positions, cache=None,
             q = rope(q, positions, cfg.rope_theta)
         causal = False
 
-    new_cache = None
+    kv = {"k": k, "v": v}
     q_offset = 0
     if cache is not None:
-        pos = cache["pos"]
-        k = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype),
-                                         (0, pos, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype),
-                                         (0, pos, 0, 0))
-        new_cache = {"k": k, "v": v, "pos": pos + x.shape[1]}
-        q_offset = pos
+        q_offset = cache["pos"]
+        kv, k, v = append_kv(cache, k, v, layer)
 
     out = attention(q, k, v, causal=causal, q_offset=q_offset,
                     window=window, q_chunk=q_chunk, unroll=unroll)
     # mask out not-yet-written cache slots is handled by the causal mask
     # (q_offset bounds the attended range).
     y = jnp.einsum("bshk,hkd->bsd", out, p[f"{prefix}/wo"])
-    return y, new_cache
+    return y, kv
 
 
 # -------------------------------------------------------------------- MLP

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .common import ParamBuilder, Rules, shard_act, remat_policy
+from .common import ParamBuilder, Rules, shard_act
 from .config import ModelConfig
 from .layers import apply_attn, init_attn, init_mlp, init_norm, mlp, rmsnorm
 from .transformer import DenseModel
@@ -247,64 +247,15 @@ class MoEModel(DenseModel):
 
         return build
 
-    def _apply_block(self, p, x, *, positions, cache=None, q_chunk=None):
+    def _apply_block(self, p, x, *, positions, cache=None, layer=None,
+                     q_chunk=None, window=None):
         cfg = self.cfg
         h, new_cache = apply_attn(p, cfg, rmsnorm(x, p["ln1"], cfg.eps),
                                   positions=positions, cache=cache,
-                                  q_chunk=q_chunk)
+                                  layer=layer, q_chunk=q_chunk, window=window)
         x = shard_act(x + h, self.act_spec, self.rules)
         hn = rmsnorm(x, p["ln2"], cfg.eps)
         y = moe_ffn(p, cfg, hn, self.rules)
         if cfg.dense_residual:
             y = y + mlp(p, cfg, hn, prefix="dense_mlp")
         return shard_act(x + y, self.act_spec, self.rules), new_cache
-
-    # override the scan bodies to use the MoE block
-    def _scan_blocks(self, params, x, positions, q_chunk, window=None):
-        from .common import flat_get
-        blocks = flat_get(params, self.block_key)
-
-        def body(h, layer_p):
-            h, _ = self._apply_block(layer_p, h, positions=positions,
-                                     q_chunk=q_chunk)
-            return h, None
-
-        body = jax.checkpoint(body, policy=remat_policy())
-        x, _ = jax.lax.scan(body, x, blocks)
-        return x
-
-    def _blocks_with_cache(self, params, x, cache, q_chunk=None):
-        from .common import flat_get
-        blocks = flat_get(params, self.block_key)
-        positions = cache["pos"] + jnp.arange(x.shape[1])
-
-        def body(h, xs):
-            layer_p, k_l, v_l = xs
-            lcache = {"k": k_l, "v": v_l, "pos": cache["pos"]}
-            h, new_c = self._apply_block(layer_p, h, positions=positions,
-                                         cache=lcache, q_chunk=q_chunk)
-            return h, (new_c["k"], new_c["v"])
-
-        x, (ks, vs) = jax.lax.scan(body, x, (blocks, cache["k"], cache["v"]))
-        return x, {"k": ks, "v": vs, "pos": cache["pos"] + x.shape[1]}
-
-    def probe_block(self):
-        cfg = self.cfg
-
-        def fn(layer_p, x):
-            positions = jnp.arange(x.shape[1])
-            y, _ = self._apply_block(layer_p, x, positions=positions)
-            return y
-
-        return fn, cfg.n_layers
-
-    def probe_block_decode(self):
-        cfg = self.cfg
-
-        def fn(layer_p, x, k, v, pos):
-            positions = pos + jnp.arange(x.shape[1])
-            y, c = self._apply_block(layer_p, x, positions=positions,
-                                     cache={"k": k, "v": v, "pos": pos})
-            return y, c["k"], c["v"]
-
-        return fn, cfg.n_layers

@@ -17,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 from .common import ParamBuilder, Rules, flat_get, stack_init, shard_act, remat_policy
 from .config import ModelConfig
 from .layers import (apply_attn, cross_entropy, init_attn, init_mlp,
-                     init_norm, mlp, rmsnorm)
+                     init_norm, mlp, prefill_cache, rmsnorm)
 
 __all__ = ["DenseModel", "init_block", "apply_block"]
 
@@ -37,10 +37,12 @@ def init_block(cfg: ModelConfig, rules: Rules):
 
 
 def apply_block(p: dict, cfg: ModelConfig, x, *, positions, cache=None,
-                q_chunk=None, act_spec=None, window=None, rules=None):
-    """Pre-norm block: x + attn(ln(x)); x + mlp(ln(x)). Returns (x, cache)."""
+                layer=None, q_chunk=None, act_spec=None, window=None,
+                rules=None):
+    """Pre-norm block: x + attn(ln(x)); x + mlp(ln(x)). Returns (x, kv),
+    ``kv`` as :func:`~repro.models.layers.apply_attn` returns it."""
     h, new_cache = apply_attn(p, cfg, rmsnorm(x, p["ln1"], cfg.eps),
-                              positions=positions, cache=cache,
+                              positions=positions, cache=cache, layer=layer,
                               q_chunk=q_chunk, window=window)
     x = shard_act(x + h, act_spec, rules)
     x = x + mlp(p, cfg, rmsnorm(x, p["ln2"], cfg.eps))
@@ -99,14 +101,20 @@ class DenseModel:
             x = jnp.concatenate([vis, x], axis=1)
         return shard_act(x, self.act_spec, self.rules)
 
+    def _apply_block(self, p, x, *, positions, cache=None, layer=None,
+                     q_chunk=None, window=None):
+        """One decoder block of this family (MoE overrides it)."""
+        return apply_block(p, self.cfg, x, positions=positions, cache=cache,
+                           layer=layer, q_chunk=q_chunk,
+                           act_spec=self.act_spec, window=window,
+                           rules=self.rules)
+
     def _scan_blocks(self, params, x, positions, q_chunk, window=None):
-        cfg = self.cfg
         blocks = flat_get(params, self.block_key)
 
         def body(h, layer_p):
-            h, _ = apply_block(layer_p, cfg, h, positions=positions,
-                               q_chunk=q_chunk, act_spec=self.act_spec,
-                               window=window, rules=self.rules)
+            h, _ = self._apply_block(layer_p, h, positions=positions,
+                                     q_chunk=q_chunk, window=window)
             return h, None
 
         body = jax.checkpoint(body, policy=remat_policy())
@@ -133,12 +141,15 @@ class DenseModel:
 
     # ------------------------------------------------------------ serving
     def cache_shape(self, batch_size: int, max_seq: int):
+        """k/v [L, max_seq, KVH, B, D]: the batch second-minor, the order
+        in which the decode step updates the cache in place
+        (:func:`~repro.models.layers.append_kv`)."""
         cfg = self.cfg
         kvh_sh = self.rules.maybe(cfg.n_kv_heads, "model")
         seq_sh = self.rules.maybe(max_seq, "model") if kvh_sh is None else None
         bsp = self.rules.maybe(batch_size, "pod", "data")
-        spec = P(None, bsp, seq_sh, kvh_sh, None)
-        shape = (cfg.n_layers, batch_size, max_seq, cfg.n_kv_heads, cfg.hd)
+        spec = P(None, seq_sh, kvh_sh, bsp, None)
+        shape = (cfg.n_layers, max_seq, cfg.n_kv_heads, batch_size, cfg.hd)
         return {"k": (shape, spec), "v": (shape, spec), "pos": ((), P())}
 
     def init_cache(self, batch_size: int, max_seq: int):
@@ -151,62 +162,68 @@ class DenseModel:
     def cache_specs(self, batch_size: int, max_seq: int):
         return {k: spec for k, (_, spec) in self.cache_shape(batch_size, max_seq).items()}
 
-    def _blocks_with_cache(self, params, x, cache, q_chunk=None):
-        cfg = self.cfg
-        blocks = flat_get(params, self.block_key)
-        positions = cache["pos"] + jnp.arange(x.shape[1])
-
-        def body(h, xs):
-            layer_p, k_l, v_l = xs
-            lcache = {"k": k_l, "v": v_l, "pos": cache["pos"]}
-            h, new_c = apply_block(layer_p, cfg, h, positions=positions,
-                                   cache=lcache, q_chunk=q_chunk,
-                                   act_spec=self.act_spec, rules=self.rules)
-            return h, (new_c["k"], new_c["v"])
-
-        x, (ks, vs) = jax.lax.scan(body, x, (blocks, cache["k"], cache["v"]))
-        new_cache = {"k": ks, "v": vs, "pos": cache["pos"] + x.shape[1]}
-        return x, new_cache
-
     def prefill(self, params, batch, max_seq: int, q_chunk=None,
                 n_logits: int = 1):
-        """-> (cache, logits of the last ``n_logits`` positions [B, n, V])."""
+        """-> (cache, logits of the last ``n_logits`` positions [B, n, V]).
+        Each layer attends over the prompt's own keys and values; the
+        cache is written from them once, padded to ``max_seq``."""
         cfg = self.cfg
         x = self.embed_inputs(params, batch)
-        cache = self.init_cache(x.shape[0], max_seq)
-        x, cache = self._blocks_with_cache(params, x, cache, q_chunk=q_chunk)
+        positions = jnp.arange(x.shape[1])
+
+        def body(h, layer_p):
+            h, kv = self._apply_block(layer_p, h, positions=positions,
+                                      q_chunk=q_chunk)
+            return h, (kv["k"], kv["v"])
+
+        x, (ks, vs) = jax.lax.scan(body, x, flat_get(params, self.block_key))
+        cache = prefill_cache(ks, vs, max_seq, cfg.pdtype)
         x = rmsnorm(x[:, -n_logits:], params["ln_f"], cfg.eps)
         return cache, (x @ params["unembed"]).astype(jnp.float32)
 
     def decode_step(self, params, cache, tokens):
-        """tokens [B, 1] -> (new_cache, logits [B, 1, V])."""
+        """tokens [B, 1] -> (new_cache, logits [B, 1, V]). The cache rides
+        in the layer scan's carry; each layer writes its new position in
+        place and attends over its own slice."""
         cfg = self.cfg
         x = params["embed"][tokens].astype(cfg.cdtype)
-        x, cache = self._blocks_with_cache(params, x, cache)
+        pos = cache["pos"]
+        positions = pos + jnp.arange(x.shape[1])
+
+        def body(carry, xs):
+            h, k, v = carry
+            layer, layer_p = xs
+            h, c = self._apply_block(layer_p, h, positions=positions,
+                                     cache={"k": k, "v": v, "pos": pos},
+                                     layer=layer)
+            return (h, c["k"], c["v"]), None
+
+        (x, k, v), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (jnp.arange(cfg.n_layers), flat_get(params, self.block_key)))
         x = rmsnorm(x, params["ln_f"], cfg.eps)
+        cache = {"k": k, "v": v, "pos": pos + x.shape[1]}
         return cache, (x @ params["unembed"]).astype(jnp.float32)
 
     # ------------------------------------------------------------- probes
     def probe_block(self):
         """(fn, multiplier): one decoder block, for exact per-layer costs."""
-        cfg = self.cfg
 
         def fn(layer_p, x):
             positions = jnp.arange(x.shape[1])
-            y, _ = apply_block(layer_p, cfg, x, positions=positions,
-                               act_spec=self.act_spec, rules=self.rules)
+            y, _ = self._apply_block(layer_p, x, positions=positions)
             return y
 
-        return fn, cfg.n_layers
+        return fn, self.cfg.n_layers
 
     def probe_block_decode(self):
-        cfg = self.cfg
+        """(fn, multiplier): one decoder block's decode step over one
+        layer's cache k/v [B, Smax, KVH, D]."""
 
         def fn(layer_p, x, k, v, pos):
             positions = pos + jnp.arange(x.shape[1])
-            y, c = apply_block(layer_p, cfg, x, positions=positions,
-                               cache={"k": k, "v": v, "pos": pos},
-                               act_spec=self.act_spec, rules=self.rules)
+            y, c = self._apply_block(layer_p, x, positions=positions,
+                                     cache={"k": k, "v": v, "pos": pos})
             return y, c["k"], c["v"]
 
-        return fn, cfg.n_layers
+        return fn, self.cfg.n_layers

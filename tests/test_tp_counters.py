@@ -3,7 +3,10 @@
 4 kv heads), every ``serve.prefill`` and ``serve.decode`` span carries the
 mesh width and the all-gathers and received bytes that the benchmark's own
 count (``perfbench/lib/tp_counts.py``) gives from the configuration's
-widths; 0 at tp=1. ``serve.generate`` carries the width."""
+widths; 0 at tp=1. ``serve.generate`` carries the width. Every
+``serve.decode`` span carries ``cache_in_place`` 1 (the step took the
+donated cache), and a second ``generate_many`` on the same engine serves
+the same tokens."""
 from __future__ import annotations
 
 import importlib.util
@@ -35,9 +38,12 @@ for tp in {widths!r}:
     eng = ServeEngine(cfg, batch={batch}, prompt_len={prompt},
                       max_seq={prompt} + {gen} + 1,
                       mesh=make_host_mesh(data=1, model=tp) if tp > 1 else None)
-    eng.generate_many([{gen}], seed=3)
-    out[tp] = [[s.name, s.args] for s in tracer.spans
-               if s.name in ("serve.generate", "serve.prefill", "serve.decode")]
+    first, = eng.generate_many([{gen}], seed=3)
+    spans = [[s.name, s.args] for s in tracer.spans
+             if s.name in ("serve.generate", "serve.prefill", "serve.decode")]
+    again, = eng.generate_many([{gen}], seed=3)
+    out[tp] = {{"spans": spans,
+               "same_tokens": first.tokens.tolist() == again.tokens.tolist()}}
 print(json.dumps(out))
 """
 
@@ -51,7 +57,7 @@ def _tp_counts():
 
 
 @pytest.fixture(scope="module")
-def spans():
+def runs():
     script = SCRIPT.format(src=str(ROOT / "src"), widths=WIDTHS, batch=BATCH,
                            prompt=PROMPT, gen=GEN)
     env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -61,6 +67,11 @@ def spans():
     assert proc.returncode == 0, proc.stderr[-3000:]
     return {int(k): v for k, v in
             json.loads(proc.stdout.strip().splitlines()[-1]).items()}
+
+
+@pytest.fixture(scope="module")
+def spans(runs):
+    return {tp: run["spans"] for tp, run in runs.items()}
 
 
 def _file_keys(tp: int) -> dict:
@@ -94,3 +105,15 @@ def test_spans_carry_the_benchmarks_count_of_the_exchange(spans, tp):
         else:
             assert args["collectives"] == 2 * keys["num_hidden_layers"] + 1
             assert args["exchange_bytes"] > 0
+
+
+@pytest.mark.parametrize("tp", WIDTHS)
+def test_decode_spans_carry_cache_in_place(spans, tp):
+    decode = [args for name, args in spans[tp] if name == "serve.decode"]
+    assert len(decode) == GEN
+    assert all(args["cache_in_place"] == 1 for args in decode), decode
+
+
+@pytest.mark.parametrize("tp", WIDTHS)
+def test_generate_many_twice_serves_the_same_tokens(runs, tp):
+    assert runs[tp]["same_tokens"]

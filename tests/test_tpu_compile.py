@@ -105,6 +105,91 @@ def test_qwen25_3b_decode_fits_one_v5e_chip(one_chip):
     assert 6e9 < used < V5E_HBM_BYTES, used
 
 
+def _whole_cache_ops(compiled, shape) -> list:
+    """(name, op of the fused computation's ROOT, or None for a copy) of
+    each fusion or copy whose output has the cache's full ``shape``."""
+    import re
+
+    want = f"[{','.join(map(str, shape))}]"
+    instr = re.compile(r"^\s*(?:ROOT )?%(\S+) = [a-z0-9]+(\[[\d,]*\])\S* "
+                       r"([\w\-]+)\((.*)$")
+    roots, ops, comp = {}, [], None
+    for line in compiled.as_text().splitlines():
+        head = re.match(r"^(?:ENTRY )?(%\S+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = instr.match(line)
+        if not m:
+            continue
+        name, shp, op, rest = m.groups()
+        if line.lstrip().startswith("ROOT "):
+            roots[comp] = op
+        if shp == want and op in ("fusion", "copy"):
+            called = re.search(r"calls=(%[\w.\-]+)", rest)
+            ops.append((name, called.group(1) if called else None))
+    return [(name, roots.get(called)) for name, called in ops]
+
+
+def _assert_cache_updated_in_place(compiled, cache):
+    """The decode step takes the donated cache as its output (aliased: the
+    k/v bytes a device holds, plus the position scalar's one tile), needs
+    almost no scratch, and writes the cache only with in-place
+    dynamic-update-slices: no whole-cache copy."""
+    shape = compiled.output_shardings[0]["k"].shard_shape(cache["k"].shape)
+    kv = 2 * int(np.prod(shape)) * cache["k"].dtype.itemsize
+    m = compiled.memory_analysis()
+    assert 0 <= m.alias_size_in_bytes - kv < 1024, (m.alias_size_in_bytes, kv)
+    assert m.temp_size_in_bytes < 0.01 * kv, m.temp_size_in_bytes
+    ops = _whole_cache_ops(compiled, shape)
+    assert ops, "no whole-cache update found"
+    assert all(root == "dynamic-update-slice" for _, root in ops), ops
+
+
+def test_qwen25_3b_decode_step_updates_its_cache_in_place(one_chip):
+    """The benchmark cell's decode step (batch 32, cache 161 + 1,024 + 8),
+    jitted with the cache donated as ``ServeEngine`` jits it, writes only
+    the new position of each layer into the cache it was given."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models import build_model
+
+    model = build_model(get_config("qwen25_3b"))
+    params = _like(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                   one_chip)
+    cache = _like(jax.eval_shape(lambda: model.init_cache(32, 1193)),
+                  one_chip)
+    tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
+    _assert_cache_updated_in_place(compiled, cache)
+
+
+def test_qwen25_3b_prefill_writes_its_cache_once(one_chip):
+    """The cell's prefill (batch 32, prompt 161) attends over the prompt's
+    own keys and writes the 1,193-slot cache once, by padding them: no
+    whole-cache copy or fusion, and under a third of the 0.45 GB of
+    scratch a prefill through a zero cache took."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.models import build_model
+
+    model = build_model(get_config("qwen25_3b"))
+    params = _like(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                   one_chip)
+    tokens = jax.ShapeDtypeStruct((32, 161), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda p, b: model.prefill(p, b, 1193)).lower(
+        params, {"tokens": tokens}).compile()
+    shape = model.cache_shape(32, 1193)["k"][0]
+    assert not _whole_cache_ops(compiled, shape), \
+        _whole_cache_ops(compiled, shape)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
+
+
 def test_yi_9b_tp4_init_and_prefill_fit_four_v5e_chips(topo):
     """Full-depth Yi-9B at tp=4: the sharded init places each parameter
     once, and the prefill (batch 8, prompt 512) fits each device."""
@@ -140,8 +225,10 @@ def test_yi_9b_tp4_init_and_prefill_fit_four_v5e_chips(topo):
 
 def test_yi_9b_tp4_decode_step_compiles_with_its_exchange(topo):
     """The Yi-9B tp=4 decode step at the benchmark cell's shapes (batch 32,
-    cache 161 + 1,024 + 8) compiles as ``jit_decode_step``, fits each
-    device, and keeps its all-gathers; the engine's counters for it are
+    cache 161 + 1,024 + 8), jitted with the cache donated as
+    ``ServeEngine`` jits it, compiles as ``jit_decode_step``, fits each
+    device, keeps its all-gathers and updates each device's shard of the
+    cache in place; the engine's counters for it are
     97 all-gathers (two a layer, one at the logits) and 3/4 of each
     gathered tensor received."""
     import jax
@@ -167,11 +254,13 @@ def test_yi_9b_tp4_decode_step_compiles_with_its_exchange(topo):
     prefill, decode = build_tp_step_fns(model, specs, mesh, max_seq=1193)
     cache, _ = jax.eval_shape(prefill, params, batch)
     tokens = jax.ShapeDtypeStruct((32, 1), jnp.int32, sharding=replicated)
-    compiled = jax.jit(decode).lower(params, cache, tokens).compile()
+    compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
     text = compiled.as_text()
     assert text.startswith("HloModule jit_decode_step")
     assert "all-gather" in text
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+    _assert_cache_updated_in_place(compiled, cache)
 
     _, dec = exchange_counts(prefill, decode, params, batch)
     gathered = cfg.n_layers * 32 * (cfg.n_heads * cfg.hd + cfg.d_ff) \
