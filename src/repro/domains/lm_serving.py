@@ -302,7 +302,7 @@ class LocalLMPlatform(_LMPlatformBase):
 
     def run(self, req: LMRequest, n_tokens: int, seed: int = 0) -> ServeRecord:
         n = self._clamp(req, n_tokens)
-        result = self.engine(req).generate(n, seed=seed)
+        result, = self.engine(req).generate_many([n], seed=seed)
         return ServeRecord(self.spec.name, req.task_id, n,
                            result.total_latency, result.prefill_latency)
 

@@ -40,8 +40,8 @@ class ServeEngine:
     """Prefill + KV-cache decode engine for one model configuration.
 
     Owns the params and the jitted prefill/decode executables. ``max_seq``
-    is fixed at construction so every ``generate`` call with
-    ``prompt_len + gen <= max_seq`` reuses the same two executables —
+    is fixed at construction so every ``generate_many`` call with
+    ``prompt_len + max(gens) <= max_seq`` reuses the same two executables —
     the engine analogue of the pricing engine's runtime-parameter batching
     (the compile unit is the (config, batch, max_seq) family, not the
     individual request). The decode step donates its cache argument: a
@@ -90,20 +90,19 @@ class ServeEngine:
                 k: NamedSharding(mesh, spec) for k, spec in specs.items()})(key)
             prefill, decode = build_tp_step_fns(self.model, specs, mesh,
                                                 self.max_seq)
-            self._prefill = jax.jit(prefill)
-            self._decode = jax.jit(decode, donate_argnums=(1,))
             pre, dec = exchange_counts(prefill, decode, self.params,
                                        self._batch_inputs(seed))
         else:
             self.params = jax.jit(self.model.init)(key)
 
+            # a named function, so the profile shows ``jit_prefill``
             def prefill(params, batch):
                 return self.model.prefill(params, batch, self.max_seq)
 
-            # a named function, so the profile shows ``jit_prefill``
-            self._prefill = jax.jit(prefill)
-            self._decode = jax.jit(self.model.decode_step, donate_argnums=(1,))
+            decode = self.model.decode_step
             pre = dec = {"collectives": 0, "exchange_bytes": 0}
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode, donate_argnums=(1,))
         self.tp = tp
         #: the attributes of every ``serve.prefill`` / ``serve.decode``
         #: span: the mesh width and the exchange one call makes
@@ -183,38 +182,6 @@ class ServeEngine:
         self._decode_args["cache_in_place"] = int(all(
             leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(cache)))
         self._warm = True
-
-    def generate(self, gen: int, seed: int = 0) -> GenerationResult:
-        """Greedy-decode ``gen`` tokens for one synthetic batch."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        if self.prompt_len + gen > self.max_seq:
-            raise ValueError(
-                f"prompt {self.prompt_len} + gen {gen} exceeds max_seq {self.max_seq}")
-        self.warm(seed)
-        batch = self._batch_inputs(seed)
-
-        t0 = time.perf_counter()
-        cache, logits = self._prefill(self.params, batch)
-        logits.block_until_ready()
-        t_prefill = time.perf_counter() - t0
-
-        toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        generated = [np.asarray(toks)]
-        lat: list[float] = []
-        for _ in range(gen):
-            t0 = time.perf_counter()
-            cache, logits = self._decode(self.params, cache, toks)
-            toks = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-            toks.block_until_ready()
-            lat.append(time.perf_counter() - t0)
-            generated.append(np.asarray(toks))
-        return GenerationResult(
-            prefill_latency=t_prefill,
-            decode_latencies=lat,
-            tokens=np.concatenate(generated, axis=1),
-        )
 
     def generate_many(self, gens: list[int], seed: int = 0) -> list[GenerationResult]:
         """Continuous batching: ``len(gens)`` streams share one running
@@ -333,7 +300,7 @@ def main(argv=None) -> int:
         log.info(f"continuous batch: {sum(gens)} tokens, engine busy "
                  f"{busy*1e3:.1f} ms (solo serves ~{solo*1e3:.1f} ms)")
         return 0
-    result = engine.generate(args.gen, seed=args.seed)
+    result, = engine.generate_many([args.gen], seed=args.seed)
 
     n = np.arange(1, len(result.decode_latencies) + 1)
     cum = np.cumsum(result.decode_latencies)

@@ -1,16 +1,22 @@
 """Tensor-parallel serving step functions (gather-based).
 
+The sharded step is the dense model's own forward
+(``DenseModel.prefill`` / ``decode_step``) run per device under
+``shard_map``; this module holds only the layouts and the wrapping.
+
 The sharded path must compute the single-device engine's logits — it is
 the same platform quoted at a different mesh shape, and the allocator's
 accountability story dies the moment "same work, wider mesh" changes the
 answer. psum-based (Megatron-style row-parallel) output projections split
-each contraction across devices and add the partial sums; this module
-instead shards only *column-parallel* weights (q/k/v heads, MLP hidden,
-unembed vocab) and **all-gathers activations** back to full width before
-every contraction-sharded matmul, which then runs replicated.
-``all_gather(tiled=True)`` concatenates shards in axis order, so gathered
-tensors are elementwise identical to their dense layout and every op
-computes the same per-element arithmetic as the dense path.
+each contraction across devices and add the partial sums; here only
+*column-parallel* weights (q/k/v heads, MLP hidden, unembed vocab) are
+sharded, and the forward **all-gathers activations** back to full width
+where a contraction meets a shard (attention output before ``wo``, MLP
+hidden before ``w_out``, the logits: ``models.layers.gather_shards``),
+so those matmuls run replicated. A tiled all-gather concatenates shards
+in axis order, so gathered tensors are elementwise identical to their
+dense layout and every op computes the same per-element arithmetic
+as the dense path.
 
 That is not a bitwise guarantee: the compiler may pick other dot and
 fusion strategies for the partitioned program's smaller shapes, which
@@ -31,8 +37,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
-from repro.models.layers import (append_kv, attention, prefill_cache,
-                                 rmsnorm, rope)
 
 __all__ = ["TPShardingError", "tp_param_specs", "tp_cache_specs",
            "build_tp_step_fns", "validate_tp", "exchange_counts"]
@@ -92,106 +96,23 @@ def tp_cache_specs() -> dict:
     return {"k": kv, "v": kv, "pos": P()}
 
 
-def _tp_forward(cfg, block_key: str, max_seq: int):
-    """Per-device workers (prefill, decode): the DenseModel forward with
-    gathers at the two contraction-sharded matmuls (attention out-proj,
-    MLP down-proj) and at the logits. Mirrors transformer.apply_block
-    exactly elsewhere; the cache goes as in ``DenseModel.prefill`` and
-    ``decode_step``."""
-    eps, theta = cfg.eps, cfg.rope_theta
-    pre = block_key + "/"
-
-    def layer(lp, h, positions, attend):
-        """One block; ``attend(q, k, v)`` -> (attention output, what the
-        layer keeps of the cache)."""
-        xn = rmsnorm(h, lp["ln1"], eps)
-        q = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wq"])
-        k = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wk"])
-        v = jnp.einsum("bsd,dhk->bshk", xn, lp["attn/wv"])
-        if "attn/bq" in lp:
-            q = q + lp["attn/bq"]
-            k = k + lp["attn/bk"]
-            v = v + lp["attn/bv"]
-        q = rope(q, positions, theta)
-        k = rope(k, positions, theta)
-        out, kept = attend(q, k, v)
-        out = jax.lax.all_gather(out, MODEL, axis=2, tiled=True)
-        h = h + jnp.einsum("bshk,hkd->bsd", out, lp["attn/wo"])
-        xn = rmsnorm(h, lp["ln2"], eps)
-        hid = xn @ lp["mlp/w_in"]
-        if cfg.mlp_variant == "swiglu":
-            hid = jax.nn.silu(xn @ lp["mlp/w_gate"]) * hid
-        elif cfg.mlp_variant == "geglu":
-            hid = jax.nn.gelu(xn @ lp["mlp/w_gate"]) * hid
-        else:
-            hid = jax.nn.gelu(hid)
-        hid = jax.lax.all_gather(hid, MODEL, axis=2, tiled=True)
-        return h + hid @ lp["mlp/w_out"], kept
-
-    def logits(p, x):
-        x = rmsnorm(x, p["ln_f"], eps)
-        out = jax.lax.all_gather(x @ p["unembed"], MODEL, axis=2, tiled=True)
-        return out.astype(jnp.float32)
-
-    def blocks(p):
-        return {k[len(pre):]: v for k, v in p.items() if k.startswith(pre)}
-
-    def prefill(p, tokens):
-        x = p["embed"][tokens].astype(cfg.cdtype)
-        positions = jnp.arange(x.shape[1])
-
-        def attend(q, k, v):
-            return attention(q, k, v, causal=True), (k, v)
-
-        def body(h, lp):
-            return layer(lp, h, positions, attend)
-
-        x, (ks, vs) = jax.lax.scan(body, x, blocks(p))
-        return (prefill_cache(ks, vs, max_seq, cfg.pdtype),
-                logits(p, x[:, -1:]))
-
-    def decode(p, cache, tokens):
-        x = p["embed"][tokens].astype(cfg.cdtype)
-        pos0 = cache["pos"]
-        positions = pos0 + jnp.arange(x.shape[1])
-
-        def body(carry, xs):
-            h, kc, vc = carry
-            i, lp = xs
-
-            def attend(q, k, v):
-                c, k, v = append_kv({"k": kc, "v": vc, "pos": pos0}, k, v, i)
-                return (attention(q, k, v, causal=True, q_offset=pos0),
-                        (c["k"], c["v"]))
-
-            h, (kc, vc) = layer(lp, h, positions, attend)
-            return (h, kc, vc), None
-
-        (x, ks, vs), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (jnp.arange(cfg.n_layers), blocks(p)))
-        return ({"k": ks, "v": vs, "pos": pos0 + tokens.shape[1]},
-                logits(p, x))
-
-    return prefill, decode
-
-
 def build_tp_step_fns(model, pspecs: dict, mesh, max_seq: int):
     """(prefill, decode) callables matching ``DenseModel.prefill`` /
     ``decode_step`` signatures, tensor-parallel over ``mesh``'s model
     axis, for parameters laid out as ``pspecs`` (:func:`tp_param_specs`).
+    Each device runs the model's own forward on its shards; the forward
+    gathers an activation where it is narrower than the weight that
+    consumes it (:func:`repro.models.layers.gather_shards`).
     Raises :class:`TPShardingError` for unshardable shapes."""
-    cfg = model.cfg
-    tp = mesh.shape[MODEL]
-    validate_tp(cfg, tp)
-    prefill_worker, decode_worker = _tp_forward(cfg, model.block_key, max_seq)
+    validate_tp(model.cfg, mesh.shape[MODEL])
     cache_spec = tp_cache_specs()
     out_specs = (cache_spec, P(None, None, None))
 
-    sm_prefill = shard_map(prefill_worker, mesh,
-                           in_specs=(pspecs, P(None, None)),
-                           out_specs=out_specs, axis_names={MODEL})
-    sm_decode = shard_map(decode_worker, mesh,
+    sm_prefill = shard_map(
+        lambda p, tokens: model.prefill(p, {"tokens": tokens}, max_seq),
+        mesh, in_specs=(pspecs, P(None, None)), out_specs=out_specs,
+        axis_names={MODEL})
+    sm_decode = shard_map(model.decode_step, mesh,
                           in_specs=(pspecs, cache_spec, P(None, None)),
                           out_specs=out_specs, axis_names={MODEL})
 

@@ -14,11 +14,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .common import ParamBuilder, Rules
+from .common import MODEL, ParamBuilder, Rules
 
 __all__ = ["rmsnorm", "rope", "attention", "mlp", "init_attn", "init_mlp",
            "cross_entropy", "apply_attn", "init_norm", "append_kv",
-           "prefill_cache"]
+           "prefill_cache", "gather_shards"]
 
 
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
@@ -30,6 +30,18 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
 
 def init_norm(b: ParamBuilder, name: str, d: int) -> None:
     b.ones(name, (d,), P(None))
+
+
+def gather_shards(x: jnp.ndarray, axis: int, width: int) -> jnp.ndarray:
+    """``x`` at its full ``width`` along ``axis``, the width of the weight
+    that consumes it. Narrower, ``x`` is one device's shard inside the
+    tensor-parallel step's ``shard_map`` (:mod:`repro.launch.tp`), and
+    the shards are all-gathered on the model axis in axis order, so the
+    result is elementwise the dense layout. Elsewhere shapes are global
+    and nothing is emitted."""
+    if x.shape[axis] >= width:
+        return x
+    return jax.lax.all_gather(x, MODEL, axis=axis, tiled=True)
 
 
 # ------------------------------------------------------------------- RoPE
@@ -238,7 +250,8 @@ def apply_attn(p: dict, cfg, x: jnp.ndarray, *, positions, cache=None,
                     window=window, q_chunk=q_chunk, unroll=unroll)
     # mask out not-yet-written cache slots is handled by the causal mask
     # (q_offset bounds the attended range).
-    y = jnp.einsum("bshk,hkd->bsd", out, p[f"{prefix}/wo"])
+    wo = p[f"{prefix}/wo"]
+    y = jnp.einsum("bshk,hkd->bsd", gather_shards(out, 2, wo.shape[0]), wo)
     return y, kv
 
 
@@ -262,7 +275,8 @@ def mlp(p: dict, cfg, x: jnp.ndarray, prefix: str = "mlp") -> jnp.ndarray:
         h = jax.nn.gelu(x @ p[f"{prefix}/w_gate"]) * h
     else:
         h = jax.nn.gelu(h)
-    return h @ p[f"{prefix}/w_out"]
+    w_out = p[f"{prefix}/w_out"]
+    return gather_shards(h, h.ndim - 1, w_out.shape[0]) @ w_out
 
 
 # ---------------------------------------------------------- loss / logits

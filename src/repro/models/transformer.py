@@ -16,8 +16,8 @@ from jax.sharding import PartitionSpec as P
 
 from .common import ParamBuilder, Rules, flat_get, stack_init, shard_act, remat_policy
 from .config import ModelConfig
-from .layers import (apply_attn, cross_entropy, init_attn, init_mlp,
-                     init_norm, mlp, prefill_cache, rmsnorm)
+from .layers import (apply_attn, cross_entropy, gather_shards, init_attn,
+                     init_mlp, init_norm, mlp, prefill_cache, rmsnorm)
 
 __all__ = ["DenseModel", "init_block", "apply_block"]
 
@@ -178,8 +178,7 @@ class DenseModel:
 
         x, (ks, vs) = jax.lax.scan(body, x, flat_get(params, self.block_key))
         cache = prefill_cache(ks, vs, max_seq, cfg.pdtype)
-        x = rmsnorm(x[:, -n_logits:], params["ln_f"], cfg.eps)
-        return cache, (x @ params["unembed"]).astype(jnp.float32)
+        return cache, self._logits(params, x[:, -n_logits:])
 
     def decode_step(self, params, cache, tokens):
         """tokens [B, 1] -> (new_cache, logits [B, 1, V]). The cache rides
@@ -201,9 +200,17 @@ class DenseModel:
         (x, k, v), _ = jax.lax.scan(
             body, (x, cache["k"], cache["v"]),
             (jnp.arange(cfg.n_layers), flat_get(params, self.block_key)))
-        x = rmsnorm(x, params["ln_f"], cfg.eps)
         cache = {"k": k, "v": v, "pos": pos + x.shape[1]}
-        return cache, (x @ params["unembed"]).astype(jnp.float32)
+        return cache, self._logits(params, x)
+
+    def _logits(self, params, x):
+        """Final norm and unembedding of hidden states ``x``, as float32
+        logits over the whole vocabulary (gathered inside the
+        tensor-parallel step, whose unembedding is vocab-sharded)."""
+        x = rmsnorm(x, params["ln_f"], self.cfg.eps)
+        logits = gather_shards(x @ params["unembed"], x.ndim - 1,
+                               self.cfg.vocab)
+        return logits.astype(jnp.float32)
 
     # ------------------------------------------------------------- probes
     def probe_block(self):

@@ -318,7 +318,8 @@ def test_sharded_engine_logits_match_single_device_bitwise():
     tp = ServeEngine(cfg, batch=2, prompt_len=8, max_seq=16,
                      mesh=make_host_mesh(data=1, model=2))
     assert_tp_parity(ref.probe_logits(steps=4), tp.probe_logits(steps=4))
-    r0, r1 = ref.generate(4, seed=0), tp.generate(4, seed=0)
+    r0, = ref.generate_many([4], seed=0)
+    r1, = tp.generate_many([4], seed=0)
     np.testing.assert_array_equal(r0.tokens, r1.tokens)
 
 
@@ -340,10 +341,14 @@ _PARITY_SCRIPT = textwrap.dedent("""
     ]:
         ref = ServeEngine(cfg, batch=2, prompt_len=8, max_seq=16)
         base = ref.probe_logits(steps=4)
+        served, = ref.generate_many([4], seed=0)
         for tp in widths:
             eng = ServeEngine(cfg, batch=2, prompt_len=8, max_seq=16,
                               mesh=make_host_mesh(data=1, model=tp))
             assert_tp_parity(base, eng.probe_logits(steps=4))
+            # the serving loop: the same greedy tokens as one device
+            got, = eng.generate_many([4], seed=0)
+            np.testing.assert_array_equal(served.tokens, got.tokens)
             leaf = eng.params["blocks/mlp/w_in"]
             # placed sharded by the init: each device holds 1/tp of it
             assert leaf.addressable_shards[0].data.shape[-1] == cfg.d_ff // tp
