@@ -184,71 +184,76 @@ def _mc_batch_kernel(params_ref, tid_ref, kind_ref, nact_ref, seed_ref, o_ref,
     resident across its path blocks (the ``"arbitrary"`` grid axis): row 0
     accumulates per-lane sums of the payoff, row 1 of its square.
 
-    Paths with global id >= n_active (batch padding for ragged per-task
-    path counts) are simulated but masked out of the payoff sums, so each
+    The grid pads every task to the widest task's blocks. A block that
+    starts at or past its task's own ``n_active`` does no work: the tile
+    already holds that task's sums, and the block would only add zeros to
+    them. In the task's last, partial block the paths with global id >=
+    n_active are simulated but masked out of the payoff sums. So each
     task's moments are exactly those of its first n_active counter-based
-    draws — bit-identical in distribution to the per-task run.
+    draws, bit for bit those of a launch of that task alone.
     """
     rows = block_paths // LANES
     task = pl.program_id(0)
     block = pl.program_id(1)
-
     base = (block * block_paths).astype(jnp.uint32)
-    pid = (base
-           + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANES), 0) * LANES
-           + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANES), 1))
-    k0 = seed_ref[0]
-    k1 = tid_ref[task]
-
-    def param(name):
-        return params_ref[task, COL[name]]
-
-    spot = jnp.full((rows, LANES), param("spot"))
-    rate = param("rate")
-    dt = param("dt")
-
-    if model_kind == "black-scholes":
-        f = bs_step_fn(rate, param("vol"), dt)
-
-        def step(s_idx, state):
-            s, acc, mn, mx = state
-            z = normal_pair(k0, k1, pid, jnp.full_like(pid, s_idx))
-            s = f(s, z)
-            return s, acc + s, jnp.minimum(mn, s), jnp.maximum(mx, s)
-
-        init: Any = (spot, jnp.zeros_like(spot), spot, spot)
-        s_t, acc, mn, mx = jax.lax.fori_loop(0, n_steps, step, init)
-    else:
-        f = heston_step_fn(rate, param("kappa"), param("theta"), param("xi"),
-                           param("rho"), dt)
-
-        def step(s_idx, state):
-            s, v, acc, mn, mx = state
-            z = normal_pair(k0, k1, pid, jnp.full_like(pid, s_idx))
-            s, v = f((s, v), z)
-            return s, v, acc + s, jnp.minimum(mn, s), jnp.maximum(mx, s)
-
-        init = (spot, jnp.full((rows, LANES), param("v0")),
-                jnp.zeros_like(spot), spot, spot)
-        s_t, _, acc, mn, mx = jax.lax.fori_loop(0, n_steps, step, init)
-
-    avg = acc / jnp.float32(n_steps)
-    pay = payoff_from_stats_coded(
-        s_t, avg, mn, mx,
-        strike=param("strike"), lower=param("lower"), upper=param("upper"),
-        payout=param("payout"), call_sign=param("call_sign"),
-        kind=kind_ref[task])
-    pay = jnp.where(pid < nact_ref[task], pay, jnp.float32(0.0))
 
     @pl.when(block == 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
-    lane_sum = jnp.sum(pay, axis=0, keepdims=True)
-    lane_sq = jnp.sum(pay * pay, axis=0, keepdims=True)
-    o_ref[...] += jnp.where(row == 0, lane_sum,
-                            jnp.where(row == 1, lane_sq, jnp.float32(0.0)))
+    @pl.when(base < nact_ref[task])
+    def _():
+        pid = (base
+               + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANES), 0) * LANES
+               + jax.lax.broadcasted_iota(jnp.uint32, (rows, LANES), 1))
+        k0 = seed_ref[0]
+        k1 = tid_ref[task]
+
+        def param(name):
+            return params_ref[task, COL[name]]
+
+        spot = jnp.full((rows, LANES), param("spot"))
+        rate = param("rate")
+        dt = param("dt")
+
+        if model_kind == "black-scholes":
+            f = bs_step_fn(rate, param("vol"), dt)
+
+            def step(s_idx, state):
+                s, acc, mn, mx = state
+                z = normal_pair(k0, k1, pid, jnp.full_like(pid, s_idx))
+                s = f(s, z)
+                return s, acc + s, jnp.minimum(mn, s), jnp.maximum(mx, s)
+
+            init: Any = (spot, jnp.zeros_like(spot), spot, spot)
+            s_t, acc, mn, mx = jax.lax.fori_loop(0, n_steps, step, init)
+        else:
+            f = heston_step_fn(rate, param("kappa"), param("theta"),
+                               param("xi"), param("rho"), dt)
+
+            def step(s_idx, state):
+                s, v, acc, mn, mx = state
+                z = normal_pair(k0, k1, pid, jnp.full_like(pid, s_idx))
+                s, v = f((s, v), z)
+                return s, v, acc + s, jnp.minimum(mn, s), jnp.maximum(mx, s)
+
+            init = (spot, jnp.full((rows, LANES), param("v0")),
+                    jnp.zeros_like(spot), spot, spot)
+            s_t, _, acc, mn, mx = jax.lax.fori_loop(0, n_steps, step, init)
+
+        avg = acc / jnp.float32(n_steps)
+        pay = payoff_from_stats_coded(
+            s_t, avg, mn, mx,
+            strike=param("strike"), lower=param("lower"), upper=param("upper"),
+            payout=param("payout"), call_sign=param("call_sign"),
+            kind=kind_ref[task])
+        pay = jnp.where(pid < nact_ref[task], pay, jnp.float32(0.0))
+
+        row = jax.lax.broadcasted_iota(jnp.int32, (SUBLANES, LANES), 0)
+        lane_sum = jnp.sum(pay, axis=0, keepdims=True)
+        lane_sq = jnp.sum(pay * pay, axis=0, keepdims=True)
+        o_ref[...] += jnp.where(row == 0, lane_sum,
+                                jnp.where(row == 1, lane_sq, jnp.float32(0.0)))
 
 
 def mc_moments_batch_kernel_call(batch: TaskBatch, n_active, seed,

@@ -314,10 +314,12 @@ def price_batch(tasks: Sequence[PricingTask], n_paths,
     """Price many tasks with one compiled launch per compilation group.
 
     ``n_paths`` is an int (shared by all tasks) or a per-task sequence;
-    ragged path counts within a group are padded (to the next chunk for the
-    jnp oracle, path block for the Pallas kernel) and masked, so every
+    ragged path counts within a group are padded and masked, so every
     task's estimate uses exactly its own first ``n`` counter-based draws —
-    identical in distribution to a per-task run.
+    identical in distribution to a per-task run. The jnp oracle runs every
+    task to the widest count's next chunk. The Pallas kernel runs each
+    task to its own last path block, masks that block's padded paths, and
+    skips the blocks wholly past the task's count.
 
     Tasks are grouped by :func:`launch_key` — (model kind, n_steps), the
     only *structural* task properties — so a full mixed Table 1 workload
@@ -328,6 +330,8 @@ def price_batch(tasks: Sequence[PricingTask], n_paths,
     uniform-n hot paths (benchmark ladders, calibration) stay one launch.
     Each bucket is a ``pricing.pack`` span (grouping and packing) and a
     ``pricing.finalize`` span (the per-task moments) on the process tracer.
+    The ``pricing.pack`` span carries ``paths``, the bucket's active paths,
+    and ``paths_run``, the paths its launch simulates.
 
     Returns one :class:`PriceResult` per task, in input order.
     """
@@ -337,17 +341,24 @@ def price_batch(tasks: Sequence[PricingTask], n_paths,
     span = default_tracer().span
     for _key, group in group_by_launch(tasks):
         for bucket in _ragged_buckets([int(ns[i]) for i, _ in group]):
-            with span("pricing.pack", track="pricing", tasks=len(bucket)):
+            with span("pricing.pack", track="pricing",
+                      tasks=len(bucket)) as pack:
                 sub = [group[k] for k in bucket]
                 batch = TaskBatch.from_tasks([t for _, t in sub])
                 n_act = np.asarray([ns[i] for i, _ in sub], dtype=np.uint32)
-            if backend == "pallas":
-                from repro.kernels import ops  # local import: kernels are optional
+                if backend == "pallas":
+                    from repro.kernels import ops  # kernels are optional
 
+                    _, paths_run = ops.launch_paths(n_act, block_paths)
+                else:
+                    n_chunks = -(-int(n_act.max()) // CHUNK_PATHS)
+                    paths_run = len(sub) * n_chunks * CHUNK_PATHS
+                pack.args["paths"] = int(n_act.sum())
+                pack.args["paths_run"] = paths_run
+            if backend == "pallas":
                 sums, sqs = ops.mc_moments_batch(batch, n_act, seed,
                                                  block_paths=block_paths)
             else:
-                n_chunks = -(-int(n_act.max()) // CHUNK_PATHS)
                 sums, sqs = _batch_moments(batch, jnp.asarray(n_act),
                                            jnp.int32(n_chunks),
                                            jnp.uint32(seed),

@@ -85,6 +85,45 @@ def test_ragged_buckets_bound_padding_waste():
                                    rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("category", ["BS-A", "H-A"])
+def test_ragged_launch_moments_are_one_task_launches_bitwise(category):
+    """A launch whose tasks' path counts differ by up to 66x gives each
+    task the sums of a launch of that task alone, to the bit: the blocks
+    past a task's own count add nothing, and its last block is masked."""
+    from repro.kernels import ops
+
+    ns = [100, 2048, 4096 + 128, 64]
+    tasks = table1_workload(seed=29, n_steps=8, categories=[(category, 4)])
+    sums, sqs = ops.mc_moments_batch(TaskBatch.from_tasks(tasks), ns, 9,
+                                     block_paths=1024)
+    for k, (t, n) in enumerate(zip(tasks, ns)):
+        one_sum, one_sq = ops.mc_moments_batch(TaskBatch.from_tasks([t]), [n],
+                                               9, block_paths=1024)
+        assert float(sums[k]) == float(one_sum[0]) > 0
+        assert float(sqs[k]) == float(one_sq[0])
+
+
+@pytest.mark.parametrize("backend,ns,paths_run", [
+    # buckets {64, 100} and {2048, 4224}: each task to its own last block
+    ("pallas", [100, 2048, 4096 + 128, 64], [1024 + 1024, 2048 + 5120]),
+    # a uniform bucket runs its whole grid, T x n_pad
+    ("pallas", [1500, 1500, 1500], [3 * 2048]),
+    # the jnp oracle runs every task of a bucket to its widest chunk
+    ("jnp", [100, 2048, 4096 + 128, 64], [2 * 512, 2 * 9 * 512]),
+])
+def test_pack_spans_count_active_and_simulated_paths(backend, ns, paths_run,
+                                                     monkeypatch):
+    import repro.obs.trace as trace_mod
+
+    tracer = trace_mod.Tracer()
+    monkeypatch.setattr(trace_mod, "_DEFAULT", tracer)
+    tasks = table1_workload(seed=30, n_steps=8, categories=[("H-A", len(ns))])
+    price_batch(tasks, ns, seed=1, backend=backend, block_paths=1024)
+    packs = [s.args for s in tracer.spans if s.name == "pricing.pack"]
+    assert sum(a["paths"] for a in packs) == sum(ns)
+    assert sorted(a["paths_run"] for a in packs) == paths_run
+
+
 def test_price_is_thin_wrapper_over_batch_of_one():
     task = table1_workload(seed=23, n_steps=8, categories=[("H-DB", 1)])[0]
     a = price(task, 1024, seed=7)
